@@ -604,20 +604,9 @@ def _run_once_inner(
         screener.rearm(restart_step)
     stats.config["sdc"] = dict(scfg)
     m_sdc_checks = metrics.counter("sdc_checks", **mlabels)
-    # The reference side of the live model-vs-measured residual gauge:
-    # what the ICI model projects one step should cost on this exact
-    # config. Computed once — the observed p50 moves, the projection
-    # does not.
-    proj_us = icimodel.projected_step_us_for(sim)
-    metrics.gauge("comm_hidden_us_per_step", **mlabels).set(
-        comm.get("hidden_us")
-    )
-    metrics.gauge("comm_exposed_us_per_step", **mlabels).set(
-        comm.get("exposed_us")
-    )
     # s-step exchange visibility (docs/TEMPORAL.md): exchanges and
-    # ghost bytes per step make the halo_depth amortization legible on
-    # the same scrape that carries the hidden/exposed comm split.
+    # ghost bytes per step (schedule counts) make the halo_depth
+    # amortization legible on the scrape.
     metrics.gauge("comm_exchanges_per_step", **mlabels).set(
         comm.get("exchanges_per_step")
     )
@@ -636,18 +625,6 @@ def _run_once_inner(
             metrics.gauge(
                 "device_peak_bytes_in_use", device=ms["device"]
             ).set(ms["peak_bytes_in_use"])
-        # Model-vs-measured residual (docs/OBSERVABILITY.md): observed
-        # step-latency p50 minus the icimodel projection — calibration
-        # drift, live on the same scrape as the latency itself.
-        if proj_us is not None and hasattr(m_step_us, "percentile"):
-            p50 = m_step_us.percentile(50)
-            if p50 is not None:
-                metrics.gauge(
-                    "model_projected_step_us", **mlabels
-                ).set(round(proj_us, 1))
-                metrics.gauge(
-                    "model_vs_measured_residual_us", **mlabels
-                ).set(round(p50 - proj_us, 1))
 
     evs.emit(
         "run_start", step=restart_step, attempt=attempt,
@@ -1177,25 +1154,11 @@ def _run_once_inner(
             )
         if sim.xstats_enabled:
             # Executable analytics (obs/xstats.py): the per-compile
-            # records captured by the runner registrations, plus the
-            # model-vs-measured residual so a stats reader sees the
-            # calibration drift the gauge showed live.
+            # records captured by the runner registrations.
             from .obs import xstats as obs_xstats
 
             xinfo = obs_xstats.summarize(sim.executables)
             xinfo["records"] = list(sim.executables)
-            xinfo["model_projected_step_us"] = (
-                round(proj_us, 1) if proj_us is not None else None
-            )
-            p50 = (
-                m_step_us.percentile(50)
-                if hasattr(m_step_us, "percentile") else None
-            )
-            xinfo["observed_p50_us"] = p50
-            xinfo["model_vs_measured_residual_us"] = (
-                round(p50 - proj_us, 1)
-                if p50 is not None and proj_us is not None else None
-            )
             stats.record_executables(xinfo)
         stats.maybe_write()
         if settings.verbose:

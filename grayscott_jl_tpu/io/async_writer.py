@@ -192,12 +192,18 @@ class AsyncStepWriter:
             self._busy[phase] = self._busy.get(phase, 0.0) + seconds
 
     def _write_one(self, step, snapshot, targets) -> None:
+        # The worker's writes under the driver's phase names, on the
+        # profiler timeline (gs.<phase> on this thread's track).
+        from ..obs.trace import annotation
+
         t = time.perf_counter()
-        blocks = snapshot.blocks()
+        with annotation(_D2H, step=step):
+            blocks = snapshot.blocks()
         self._add_busy(_D2H, time.perf_counter() - t)
         for phase, fn in targets:
             t = time.perf_counter()
-            fn(step, blocks)
+            with annotation(phase, step=step):
+                fn(step, blocks)
             self._add_busy(phase, time.perf_counter() - t)
         self._written += 1
         self._m_written.inc()

@@ -32,6 +32,15 @@ Device-side timelines are a separate tool: ``GS_PROFILE=start:stop``
 ``jax.profiler.start_trace``/``stop_trace`` so the XLA timeline of
 exactly the interesting rounds lands in ``GS_PROFILE_DIR`` without
 paying profiler overhead for the whole run.
+
+The two meet on the profiler's clock: every :meth:`SpanTracer.span`
+(and the null tracer's, so with ``GS_TRACE`` unset too) is also a
+``jax.profiler.TraceAnnotation`` named ``gs.<name>`` (:func:`annotation`).
+Whenever a capture is open (``GS_PROFILE``, ``GS_TPU_PROFILE``, or any
+caller's ``jax.profiler.start_trace``), the program's spans land in the
+``.xplane.pb`` host plane beside the device ops; with none open a span
+costs one TraceMe check. Heartbeat-fed :meth:`SpanTracer.edge` phases
+mark the same boundaries as the nested spans and are not bridged.
 """
 
 from __future__ import annotations
@@ -48,6 +57,7 @@ __all__ = [
     "NULL_TRACER",
     "ProfileWindow",
     "SpanTracer",
+    "annotation",
     "get_tracer",
     "reset_tracer",
     "validate_trace",
@@ -56,6 +66,36 @@ __all__ = [
 #: tid of the driver-phase edge track (heartbeat-fed spans); real
 #: threads are numbered from 1 in registration order.
 EDGE_TID = 0
+
+#: Prefix of the program's spans on the profiler timeline.
+PROFILER_PREFIX = "gs."
+
+
+def annotation(name, **attrs):
+    """The profiler side of a span: a ``jax.profiler.TraceAnnotation``
+    named ``gs.<name>`` with ``attrs`` as its metadata (None values
+    left out). A no-op context until jax is imported by someone else —
+    this module never imports it."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return contextlib.nullcontext()
+    return prof.TraceAnnotation(
+        PROFILER_PREFIX + str(name),
+        **{k: v for k, v in attrs.items() if v is not None},
+    )
+
+
+@contextlib.contextmanager
+def _annotated(name, step, attrs):
+    """:func:`annotation` around the block. Yields ``attrs``: keys the
+    block adds (an outcome known only at its end, such as a compile's
+    cache hit or miss) are set on the annotation before it closes."""
+    n = len(attrs)
+    ann = annotation(name, step=step, **attrs)
+    with ann:
+        yield attrs
+        if len(attrs) > n and hasattr(ann, "set_metadata"):
+            ann.set_metadata(**{k: attrs[k] for k in list(attrs)[n:]})
 
 
 def _proc_index() -> int:
@@ -87,14 +127,14 @@ def rank_path(path: str) -> str:
 
 
 class _NullTracer:
-    """Shared no-op tracer: ``GS_TRACE`` unset costs one attribute
-    check and a no-op call per boundary, nothing more."""
+    """Shared no-op tracer: ``GS_TRACE`` unset records nothing; a span
+    is only its profiler annotation (one TraceMe check with no capture
+    open)."""
 
     enabled = False
-    _cm = contextlib.nullcontext()
 
     def span(self, name, phase=None, step=None, **attrs):
-        return self._cm
+        return _annotated(name, step, attrs)
 
     def edge(self, phase, step=None) -> None:
         pass
@@ -202,10 +242,13 @@ class SpanTracer:
     @contextlib.contextmanager
     def span(self, name, phase=None, step=None, **attrs):
         """A nested timing span around a host-side block (LIFO per
-        thread, so the exported intervals nest by construction)."""
+        thread, so the exported intervals nest by construction), also
+        a profiler annotation. Yields ``attrs``; keys the block adds
+        are exported with the span."""
         t0 = self._now_us()
         try:
-            yield
+            with _annotated(name, step, attrs):
+                yield attrs
         finally:
             self._complete(name, t0, self._now_us() - t0,
                            tid=self._tid(), phase=phase, step=step,

@@ -42,6 +42,7 @@ __all__ = [
     "capture",
     "collective_counts",
     "cache_listing",
+    "cache_outcome",
     "instrument_compile",
     "publish",
     "resolve_xstats",
@@ -173,14 +174,19 @@ def capture(compiled, *, name: str, compile_s: float,
         pass
 
     if cache_dir is not None:
-        after = cache_listing(cache_dir)
-        if cache_before is None or after is None:
-            rec["cache"] = "unknown"
-        else:
-            # A compile that wrote a new cache entry was a miss; one
-            # that left the directory untouched was served from it.
-            rec["cache"] = "miss" if after - cache_before else "hit"
+        rec["cache"] = cache_outcome(cache_dir, cache_before)
     return rec
+
+
+def cache_outcome(cache_dir: str, before: Optional[frozenset]) -> str:
+    """``hit``, ``miss`` or ``unknown`` for the compile since
+    ``before`` (:func:`cache_listing` of ``cache_dir``)."""
+    after = cache_listing(cache_dir)
+    if before is None or after is None:
+        return "unknown"
+    # A compile that wrote a new cache entry was a miss; one that left
+    # the directory untouched was served from it.
+    return "miss" if after - before else "hit"
 
 
 def publish(rec: dict, *, metrics=None, events=None) -> None:
@@ -225,26 +231,35 @@ def instrument_compile(sim, fn, nsteps: int):
     """
     import jax.numpy as jnp
 
+    from .trace import get_tracer
+
     cache_dir = sim.compile_cache_dir
     before = cache_listing(cache_dir)
-    try:
-        t0 = time.perf_counter()
-        lowered = fn.lower(
-            *sim.fields, sim.base_key, jnp.int32(sim.step), sim.params
-        )
-        compiled = lowered.compile()
-        compile_s = time.perf_counter() - t0
-    except Exception as e:  # noqa: BLE001 — never break the run
-        import sys
+    name = f"runner[{nsteps}]"
+    # The "which step recompiled" span: lowering plus compile or cache
+    # load, with the runner's name and the cache outcome.
+    with get_tracer().span("compile", step=sim.step, runner=name) as attrs:
+        try:
+            t0 = time.perf_counter()
+            lowered = fn.lower(
+                *sim.fields, sim.base_key, jnp.int32(sim.step), sim.params
+            )
+            compiled = lowered.compile()
+            compile_s = time.perf_counter() - t0
+        except Exception as e:  # noqa: BLE001 — never break the run
+            import sys
 
-        print(
-            f"gray-scott: warning: executable analytics capture failed "
-            f"for the {nsteps}-step runner ({e}); running uninstrumented",
-            file=sys.stderr,
-        )
-        return fn
+            print(
+                f"gray-scott: warning: executable analytics capture "
+                f"failed for the {nsteps}-step runner ({e}); running "
+                f"uninstrumented",
+                file=sys.stderr,
+            )
+            return fn
+        if cache_dir is not None:
+            attrs["cache"] = cache_outcome(cache_dir, before)
     rec = capture(
-        compiled, name=f"runner[{nsteps}]", compile_s=compile_s,
+        compiled, name=name, compile_s=compile_s,
         cache_dir=cache_dir, cache_before=before,
         extra={"nsteps": nsteps,
                "kernel": sim.kernel_language,

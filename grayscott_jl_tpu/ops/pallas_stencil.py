@@ -780,10 +780,11 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "bx", "use_noise", "interpret", "fuse",
-                     "detect_races", "mid_bf16"),
+                     "detect_races", "mid_bf16", "variant"),
 )
 def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
-                interpret, fuse, detect_races=False, mid_bf16=False):
+                interpret, fuse, detect_races=False, mid_bf16=False,
+                variant="chain"):
     n_f = spec.n_fields
     nx, ny, nz = fields[0].shape
     dtype = fields[0].dtype
@@ -852,13 +853,18 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
         # static jit argument so toggling it cannot be swallowed by the
         # jit cache (it is part of the cache key).
         interpret=_interpret_arg(detect_races) if interpret else False,
+        # A stable name and the variant in the custom call's
+        # kernel_metadata, so a profiler trace names each launch
+        # (no cost estimate: that would feed XLA's scheduler).
+        name=f"gs_{variant}",
+        metadata={"gs_kernel": variant, "fuse": str(fuse)},
     )(*operands)
     return tuple(out)
 
 
 def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
                allow_interpret=True, fuse=1, detect_races=False,
-               offsets=None, row=None):
+               offsets=None, row=None, variant=None):
     """``fuse`` fused steps of ``spec``'s model on interior-shaped
     fields (an n-tuple of (nx, ny, nz) arrays in declaration order).
 
@@ -894,6 +900,11 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
       the boundary constant, and the caller slices the y interior from
       the result. y is the sublane dim (8/16-granularity tiling), which
       is what makes this extension Mosaic-cheap, unlike the 128-lane z.
+
+    ``variant`` names the launch in the kernel's stamp (``gs_kernel``
+    in its ``kernel_metadata``, ``gs_<variant>`` its name): by default
+    ``chain`` without faces, ``faces`` with the 6n-tuple, ``x_chain``
+    with the 2n-tuple; the xy-chain's caller passes ``xy_chain``.
 
     ``fuse=k`` temporal blocking advances k steps per HBM pass
     (single- or multi-block; with faces only in the 2n-tuple x-chain
@@ -939,6 +950,9 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
         )
     if x_chain and fuse < 2:
         raise ValueError("the x-chain faces mode requires fuse >= 2")
+    if variant is None:
+        variant = ("x_chain" if x_chain else "chain" if faces is None
+                   else "faces")
     nx, ny, nz = fields[0].shape
     dtype = fields[0].dtype
     on_tpu = jax.default_backend() == "tpu"
@@ -984,7 +998,7 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
                     spec=spec, use_noise=use_noise,
                     allow_interpret=allow_interpret,
                     fuse=k, detect_races=detect_races,
-                    offsets=offsets, row=row,
+                    offsets=offsets, row=row, variant=variant,
                 )
                 done += k
             return fields
@@ -1045,7 +1059,7 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
         tuple(faces) if faces is not None else None,
         spec=spec, bx=bx, use_noise=use_noise, interpret=not on_tpu,
         fuse=fuse, detect_races=detect_races and not on_tpu,
-        mid_bf16=mid_bf16,
+        mid_bf16=mid_bf16, variant=variant,
     )
 
 

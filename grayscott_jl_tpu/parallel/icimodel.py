@@ -944,38 +944,3 @@ def comm_report(sim) -> dict:
         "exposed_us": exposed,
         "overlap": row["overlap"],
     }
-
-
-def projected_step_us_for(sim) -> Optional[float]:
-    """Model-projected µs/step for a CONSTRUCTED ``Simulation`` — the
-    reference side of the live model-vs-measured residual gauge
-    (``model_vs_measured_residual_us``, docs/OBSERVABILITY.md): the
-    driver subtracts this projection from the observed step-latency p50
-    so icimodel calibration drift is visible on the same scrape as the
-    latency itself. Same machinery as the autotuner's candidate scorer
-    (:func:`projected_step_us`), with every knob read off the live
-    simulation; None when the model has nothing to say (e.g. a Pallas
-    depth with no measured fuse ratio). A projection, anchored to the
-    single-chip TPU measurements — on a CPU host the residual mostly
-    measures the host, which is exactly what a reader should see."""
-    import numpy as np
-
-    try:
-        kind = sim.mesh.devices.flat[0].device_kind
-    except Exception:  # noqa: BLE001 — virtual/single-device meshes
-        kind = ""
-    link_gbps, links = fabric_for(kind)
-    lang = "pallas" if sim.kernel_language == "pallas" else "xla"
-    try:
-        return projected_step_us(
-            lang, sim.domain.dims, sim.settings.L,
-            max(1, int(sim._fuse_base())),
-            itemsize=int(np.dtype(sim.dtype).itemsize),
-            links=links, link_gbps=link_gbps,
-            overlap="auto" if getattr(sim, "comm_overlap", False)
-            else 0.0,
-            halo_depth=getattr(sim, "halo_depth", 1),
-            n_fields=int(getattr(sim.model, "n_fields", 2)),
-        )
-    except Exception:  # noqa: BLE001 — a gauge must never kill a run
-        return None

@@ -53,9 +53,21 @@ from .ops import noise as noise_ops
 from .ops import stencil, validate_kernel_language
 from .parallel import halo, temporal
 from .parallel.domain import CartDomain
+from .obs.trace import get_tracer
 from .utils.log import _is_primary
 
 AXIS_NAMES = ("x", "y", "z")
+
+
+def _gs_step(fn):
+    """``fn`` under the stable name ``gs_step``, so its jitted program
+    is ``jit_gs_step`` in HLO dumps and profiler traces (a bare
+    ``partial`` or ``shard_map`` would be ``jit__unknown``)."""
+
+    def gs_step(*args):
+        return fn(*args)
+
+    return gs_step
 
 
 def default_fuse() -> int:
@@ -1333,6 +1345,7 @@ class Simulation:
                             spec=spec, use_noise=use_noise,
                             allow_interpret=allow_interpret,
                             fuse=depth, offsets=offs_p, row=L,
+                            variant="xy_chain",
                         )
 
                     def band_kernel(fields_b, faces_b, stp, offs_b):
@@ -1525,7 +1538,8 @@ class Simulation:
 
         nf = self.model.n_fields
         fn = jax.jit(
-            self._make_step_fn(nsteps), donate_argnums=tuple(range(nf))
+            _gs_step(self._make_step_fn(nsteps)),
+            donate_argnums=tuple(range(nf)),
         )
         return self._register_runner(nsteps, fn)
 
@@ -1560,9 +1574,12 @@ class Simulation:
         runner = self._runner(nsteps)
         if not hasattr(runner, "lower"):
             return  # already AOT-compiled
-        compiled = runner.lower(
-            *self.fields, self.base_key, jnp.int32(self.step), self.params
-        ).compile()
+        with get_tracer().span("compile", step=self.step,
+                               runner=f"runner[{nsteps}]"):
+            compiled = runner.lower(
+                *self.fields, self.base_key, jnp.int32(self.step),
+                self.params,
+            ).compile()
         self._runners[nsteps] = compiled
 
     # ------------------------------------------------------------- replay
@@ -1646,7 +1663,7 @@ class Simulation:
                 # donation-sensitive (a non-donating twin drifts 1 ulp
                 # in the Pallas overlap bands).
                 fn = jax.jit(
-                    self._make_step_fn(nsteps, mesh),
+                    _gs_step(self._make_step_fn(nsteps, mesh)),
                     donate_argnums=tuple(range(self.model.n_fields)),
                 )
                 entry = self._replay_fns[key] = (fn, sharding, device)
@@ -1673,10 +1690,14 @@ class Simulation:
         """Advance the simulation ``nsteps`` steps (``public.jl:45-71``)."""
         if nsteps <= 0:
             return
-        runner = self._runner(nsteps)
-        self.fields = tuple(runner(
-            *self.fields, self.base_key, jnp.int32(self.step), self.params
-        ))
+        # Host side of one round: runner lookup (or build), argument
+        # preparation, enqueue. The steps themselves run on the device.
+        with get_tracer().span("iterate", step=self.step, nsteps=nsteps):
+            runner = self._runner(nsteps)
+            self.fields = tuple(runner(
+                *self.fields, self.base_key, jnp.int32(self.step),
+                self.params,
+            ))
         self.step += nsteps
 
     def _shard_parts(self, *arrays):
@@ -2118,7 +2139,8 @@ class Simulation:
         )
 
     def block_until_ready(self) -> None:
-        jax.block_until_ready(self.fields)
+        with get_tracer().span("sync", step=self.step):
+            jax.block_until_ready(self.fields)
 
     def layout(self):
         """The :class:`~.reshard.plan.LayoutMeta` describing this run's

@@ -19,6 +19,7 @@ import json
 import os
 
 from ..config.env import env_raw
+from ..obs.trace import NULL_TRACER
 import time
 from typing import Dict, Optional
 
@@ -38,8 +39,9 @@ class RunStats:
         self.L = L
         #: Span tracer (``obs/trace.py``): every :meth:`phase` context
         #: doubles as a trace span, so the timings RunStats was already
-        #: measuring appear on the Chrome-trace timeline for free. None
-        #: (or the null tracer) keeps the historical zero-cost path.
+        #: measuring appear on the Chrome-trace timeline for free, and
+        #: as a ``gs.<phase>`` profiler annotation — None (the null
+        #: tracer) keeps only the annotation.
         self.tracer = tracer
         #: Static run configuration echoed into the summary (mesh dims,
         #: kernel language, chain depth, ...) so a pod operator can
@@ -101,24 +103,15 @@ class RunStats:
 
     @contextlib.contextmanager
     def phase(self, name: str, step: Optional[int] = None):
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            with tr.span(name, phase=name, step=step):
-                t = time.perf_counter()
-                try:
-                    yield
-                finally:
-                    self.phases[name] = self.phases.get(name, 0.0) + (
-                        time.perf_counter() - t
-                    )
-            return
-        t = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.phases[name] = self.phases.get(name, 0.0) + (
-                time.perf_counter() - t
-            )
+        tr = self.tracer if self.tracer is not None else NULL_TRACER
+        with tr.span(name, phase=name, step=step):
+            t = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.phases[name] = self.phases.get(name, 0.0) + (
+                    time.perf_counter() - t
+                )
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + n

@@ -474,8 +474,7 @@ def report_numerics(num) -> None:
 
 def report_executables(ex) -> None:
     """The executable-analytics table: per-compile cost / memory /
-    collective counts, cache outcome, and the model-vs-measured
-    residual (docs/OBSERVABILITY.md)."""
+    collective counts and cache outcome (docs/OBSERVABILITY.md)."""
     if not ex:
         return
     print(f"== executables ({ex.get('compiles')} compiles, "
@@ -495,13 +494,6 @@ def report_executables(ex) -> None:
               f"peakB={mem.get('peak_bytes_estimate', '-')} "
               f"collectives={coll_s} "
               f"cache={r.get('cache', '-')}")
-    proj = ex.get("model_projected_step_us")
-    p50 = ex.get("observed_p50_us")
-    res = ex.get("model_vs_measured_residual_us")
-    if proj is not None or p50 is not None:
-        print(f"  model projected {proj}us/step vs observed p50 "
-              f"{round(p50, 1) if isinstance(p50, (int, float)) else '-'}"
-              f"us -> residual {res}us")
 
 
 def report_metrics_files(path: str) -> None:

@@ -62,7 +62,8 @@ def _run(tmp_path, name, extra_env=None, **config_kw):
 
 def test_stores_bitwise_identical_with_full_obs(tmp_path):
     """The transparency contract: GS_TRACE + GS_METRICS + GS_EVENTS +
-    JSON logs on vs everything off — byte-identical stores."""
+    JSON logs on, or a profiler capture open, vs everything off —
+    byte-identical stores."""
     off, res_off = _run(tmp_path, "off")
     assert res_off.returncode == 0, res_off.stderr + res_off.stdout
 
@@ -95,6 +96,27 @@ def test_stores_bitwise_identical_with_full_obs(tmp_path):
     assert len(records) >= 2
     assert "step_latency_us" in (on_dir / "prom.txt").read_text()
 
+    # A whole-run profiler capture (GS_TPU_PROFILE) with GS_TRACE unset:
+    # stores still byte-identical, and the capture's host plane holds
+    # the program's spans — the RunStats phases, the simulation's rounds.
+    prof = tmp_path / "prof"
+    prof.mkdir()
+    cfg = write_config(prof, noise=0.1, steps=STEPS, output="gs.bp",
+                       checkpoint="true", checkpoint_freq=20)
+    res = run_cli(prof, cfg,
+                  extra_env={"GS_TPU_PROFILE": str(prof / "xprof")})
+    assert res.returncode == 0, res.stderr + res.stdout
+    for store in ("gs.bp", "gs.vtk", "ckpt.bp"):
+        _assert_trees_byte_identical(off / store, prof / store)
+    from jax.profiler import ProfileData
+
+    (xplane,) = (prof / "xprof").glob("plugins/profile/*/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"gs.compute", "gs.device_to_host", "gs.output",
+            "gs.checkpoint", "gs.iterate", "gs.sync"} <= names, names
+
 
 def test_supervised_chaos_run_single_merged_timeline(tmp_path):
     """The acceptance scenario: a supervised run eating a preemption
@@ -116,7 +138,7 @@ def test_supervised_chaos_run_single_merged_timeline(tmp_path):
         "GS_HANG_BOUND_S": "40",
         # The device-side flight recorder rides the same chaos run:
         # per-boundary numerics + drift on the stream, per-compile
-        # executable analytics, residual gauge — all of it must
+        # executable analytics, compile spans — all of it must
         # survive two supervised restarts as ONE merged story.
         "GS_NUMERICS": "boundary",
         "GS_XSTATS": "1",
@@ -130,8 +152,10 @@ def test_supervised_chaos_run_single_merged_timeline(tmp_path):
     spans = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
     assert {"compile", "step_round", "io", "checkpoint",
             "drain"} <= spans, spans
-    # the nested RunStats spans ride along on their own tracks
+    # the nested RunStats spans ride along on their own tracks, and
+    # the simulation's own round and compile spans inside them
     assert {"compute", "device_to_host"} <= spans
+    assert {"iterate", "sync", "compile"} <= spans, spans
     # the watchdog expiry left its instant marker
     assert any(e["ph"] == "i" and e["name"] == "watchdog_expired"
                for e in doc["traceEvents"])
@@ -182,8 +206,7 @@ def test_supervised_chaos_run_single_merged_timeline(tmp_path):
     assert any(e["event"] == "attempt_phases" for e in stats["faults"])
 
     # -- stats: numerics section (per-boundary stats + drift) and the
-    #    executables section (cost/memory per compile + the
-    #    model-vs-measured residual the gauge showed live)
+    #    executables section (cost/memory per compile)
     num = stats["numerics"]
     assert num["mode"] == "boundary" and num["probes"] > 0
     assert set(num["last"]["fields"]) == {"u", "v"}
@@ -192,10 +215,21 @@ def test_supervised_chaos_run_single_merged_timeline(tmp_path):
     assert ex["compiles"] >= 1 and ex["records"]
     rec = ex["records"][0]
     assert rec["compile_s"] > 0 and rec["cost"]["flops"] > 0
-    assert ex["model_vs_measured_residual_us"] is not None
+    # each attempt's compile span names its runner and step (and,
+    # where a persistent cache is armed, its outcome, as the record)
+    compiles = [e["args"] for e in doc["traceEvents"]
+                if e["ph"] == "X" and e["name"] == "compile"
+                and "runner" in e["args"]]
+    assert {a["runner"] for a in compiles} == {
+        r["name"] for r in ex["records"]}, compiles
+    assert sorted(a["step"] for a in compiles) == [0, 20, 40], compiles
     gauges = {g["name"] for g in stats["metrics"]["gauges"]}
-    assert {"model_vs_measured_residual_us", "numerics_mean",
-            "numerics_drift"} <= gauges
+    assert {"numerics_mean", "numerics_drift"} <= gauges
+    # no model projection is exported as a measurement
+    assert not {"comm_hidden_us_per_step", "comm_exposed_us_per_step",
+                "model_projected_step_us",
+                "model_vs_measured_residual_us"} & gauges
+    assert "model_vs_measured_residual_us" not in ex
 
     # -- gs_report --check agrees (the CI entry point)
     proc = subprocess.run(

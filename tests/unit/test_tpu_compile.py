@@ -64,7 +64,7 @@ def mosaic(monkeypatch):
 
 
 def _compile(sharding, model_name, dtype, *, fuse=1, field_shape=None,
-             face_depth=None):
+             face_depth=None, variant=None):
     """Compile ``fuse`` fused steps of the model's generated kernel for
     one described chip; returns the compiled program's HLO text."""
     model = get_model(model_name)
@@ -89,7 +89,7 @@ def _compile(sharding, model_name, dtype, *, fuse=1, field_shape=None,
     def step(fields, params, seeds, faces):
         return pallas_stencil.fused_step(
             fields, params, seeds, faces, spec=spec, use_noise=True,
-            fuse=fuse,
+            fuse=fuse, variant=variant,
         )
 
     compiled = jax.jit(step).lower(fields, params, seeds, faces).compile()
@@ -121,3 +121,31 @@ def test_xy_chain_kernel_compiles(one_chip, mosaic):
     hlo = _compile(one_chip, "grayscott", jnp.float32, fuse=fuse,
                    field_shape=(nx, ny_p, nz), face_depth=fuse)
     assert 'custom_call_target="tpu_custom_call"' in hlo
+
+
+def _kernel_metadata(hlo):
+    """The JSON object in the Mosaic custom call's kernel_metadata."""
+    import json
+    import re
+
+    m = re.search(r"kernel_metadata=(\{[^}]*\})", hlo)
+    return json.loads(m.group(1))
+
+
+@pytest.mark.parametrize("variant,face_depth,want", [
+    pytest.param(None, None, "chain", id="one-chip-chain"),
+    pytest.param(None, 2, "x_chain", id="x-chain"),
+    pytest.param("xy_chain", 2, "xy_chain", id="xy-chain"),
+])
+def test_kernel_launch_is_stamped(one_chip, mosaic, variant, face_depth,
+                                  want):
+    """Each launch is named ``gs_<variant>`` and carries its variant and
+    chain depth in ``kernel_metadata``: what a profiler trace's op text
+    shows and the kernel readers of the benchmark key on."""
+    fuse = 2
+    shape = (L // 2, L // 2 + 8, L) if face_depth else None
+    hlo = _compile(one_chip, "grayscott", jnp.float32, fuse=fuse,
+                   field_shape=shape, face_depth=face_depth,
+                   variant=variant)
+    assert _kernel_metadata(hlo) == {"gs_kernel": want, "fuse": str(fuse)}
+    assert f"%gs_{want}" in hlo
