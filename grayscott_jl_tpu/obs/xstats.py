@@ -6,11 +6,12 @@ at the moment each step runner is compiled: ``cost_analysis()`` flops
 and bytes, ``memory_analysis()`` buffer sizes, the HLO collective
 census (how many collective-permutes/all-reduces the schedule really
 carries — the number the HLO regression tests and the icimodel
-calibration loop reason about), compile wall time, and the persistent
-compilation cache outcome (hit/miss) per executable. Records land in
-three places at capture time: ``sim.executables`` (merged into the
-RunStats ``executables`` section by the driver), one ``executable``
-record on the unified event stream, and the
+calibration loop reason about), the whole-field copy census (loop-carry
+copies an out-of-place kernel would force), compile wall time, and the
+persistent compilation cache outcome (hit/miss) per executable.
+Records land in three places at capture time: ``sim.executables``
+(merged into the RunStats ``executables`` section by the driver), one
+``executable`` record on the unified event stream, and the
 ``compiles``/``compile_cache_hits``/``compile_cache_misses`` counters
 plus a ``compile_s_total`` gauge in the metrics registry.
 
@@ -42,6 +43,7 @@ __all__ = [
     "capture",
     "collective_counts",
     "cache_listing",
+    "field_copies",
     "cache_outcome",
     "instrument_compile",
     "publish",
@@ -87,6 +89,44 @@ def collective_counts(hlo_text: str) -> dict:
     return counts
 
 
+#: An array type in HLO text, ``f32[256,256,256]`` (layout not captured).
+_ARRAY_TYPE_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,]*)\]")
+
+#: A ``copy`` or ``copy-start`` instruction and its result type (a
+#: tuple for ``copy-start``: destination, source, context).
+_COPY_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%\S+\s=\s(\(?\S+(?:,\s\S+)*)\s(copy|copy-start)\(",
+    re.MULTILINE,
+)
+
+
+def _entry_param_types(hlo_text: str) -> list:
+    """``(dtype, dims)`` of each ENTRY parameter, in order, from the
+    ENTRY computation's signature line."""
+    m = re.search(r"^ENTRY\s+%\S+\s+\((.*)\)\s*->", hlo_text, re.MULTILINE)
+    if m is None:
+        return []
+    return [t.groups() for t in re.finditer(
+        r"[\w.\-]+:\s" + _ARRAY_TYPE_RE.pattern, m.group(1))]
+
+
+def field_copies(hlo_text: str, n_fields: int) -> int:
+    """``copy`` and ``copy-start`` instructions whose result is a whole
+    field block: the type of one of the first ``n_fields`` ENTRY
+    parameters (a step runner takes its fields first). Each is a full
+    read and write of a field that the step does not need — the loop
+    carry copies an out-of-place kernel forces. Face-sized and scalar
+    copies are not counted, nor ``copy-done`` (the other half of a
+    ``copy-start``)."""
+    fields = set(_entry_param_types(hlo_text)[:n_fields])
+    count = 0
+    for m in _COPY_RE.finditer(hlo_text):
+        t = _ARRAY_TYPE_RE.search(m.group(1))
+        if t is not None and t.groups() in fields:
+            count += 1
+    return count
+
+
 def cache_listing(path: Optional[str]) -> Optional[frozenset]:
     """Entries of the persistent compile cache directory, or None when
     no cache is armed / the directory is unreadable."""
@@ -118,9 +158,12 @@ _MEMORY_ATTRS = (
 def capture(compiled, *, name: str, compile_s: float,
             cache_dir: Optional[str] = None,
             cache_before: Optional[frozenset] = None,
-            extra: Optional[dict] = None) -> dict:
+            extra: Optional[dict] = None,
+            n_fields: Optional[int] = None) -> dict:
     """One executable's analytics record, from a ``jax`` AOT-compiled
-    object. Every query is individually best-effort."""
+    object. Every query is individually best-effort. ``n_fields``
+    (the step runner's leading field arguments) arms the
+    ``field_copies`` census."""
     rec = {"name": name, "compile_s": round(compile_s, 6)}
     if extra:
         rec.update(extra)
@@ -165,6 +208,8 @@ def capture(compiled, *, name: str, compile_s: float,
     try:
         hlo = compiled.as_text()
         rec["collectives"] = collective_counts(hlo)
+        if n_fields:
+            rec["field_copies"] = field_copies(hlo, n_fields)
         # Mosaic (Pallas-on-TPU) kernels in the program: 0 means the
         # step ran no fused kernel (XLA path, or interpret mode).
         rec["tpu_custom_calls"] = hlo.count(
@@ -261,6 +306,7 @@ def instrument_compile(sim, fn, nsteps: int):
     rec = capture(
         compiled, name=name, compile_s=compile_s,
         cache_dir=cache_dir, cache_before=before,
+        n_fields=sim.model.n_fields,
         extra={"nsteps": nsteps,
                "kernel": sim.kernel_language,
                "model": sim.model.name},

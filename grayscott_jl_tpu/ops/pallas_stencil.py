@@ -26,6 +26,11 @@ traffic:
   a full extra read+write per field on ``jnp.pad``, and the padded
   ``L+2`` lane dimension rounds up to the next 128-lane tile, wasting up
   to ~50% of the vector work at L=256);
+* the result is written **in place**: each field operand aliases its
+  output, so a launch inside a loop writes the carry's own buffer and
+  XLA copies nothing back (without it, two full-field copies per launch
+  took 22% of the L=256 step); the slab pipeline orders each slab's
+  write after the neighbouring slab's overlapping read;
 * x-neighbor planes come from overlapping slab DMAs — ``(BX+2h)/BX``
   reads per cell (h = halo width) instead of 3 reads with the
   three-plane-operand trick;
@@ -86,9 +91,14 @@ from .noise import _u32, block_bits, plane_seed, uniform_pm1_block
 def _interpret_arg(detect_races: bool):
     """The ``pallas_call(interpret=...)`` value for interpret mode: the
     TPU-semantics interpreter with eager DMA, so tests see
-    deterministic copies."""
+    deterministic copies. The race detector gives each DMA one of
+    ``vector_clock_size - 1`` virtual clock slots; at its default of
+    one slot every DMA shares it and unordered DMA pairs read as
+    ordered, so it is widened to 64 — enough to catch an in-place
+    slab read overtaken by the neighbouring slab's write."""
     return pltpu.InterpretParams(
-        dma_execution_mode="eager", detect_races=detect_races
+        dma_execution_mode="eager", detect_races=detect_races,
+        vector_clock_size=64 if detect_races else None,
     )
 
 
@@ -429,7 +439,7 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
       [f_0_xlo, f_0_xhi, .., f_{n-1}_xhi (ANY, (fuse, ny, nz)),
        fuse==1 only: per-field y faces (VMEM, (nx, 1, nz)),
                      per-field z faces (VMEM, (nx, ny, 1))],
-      f_0_out .. f_{n-1}_out (ANY/HBM),
+      f_0_out .. f_{n-1}_out (ANY/HBM, aliasing f_0 .. f_{n-1}),
       scratch: in_0 .. in_{n-1} (VMEM (2, bx+2*fuse, ny, nz)),
                [mid_0 .. mid_{n-1} (VMEM (nbuf, bx+2(fuse-1), ny, nz))],
                out_0 .. out_{n-1} (VMEM (2, bx, ny, nz)),
@@ -742,18 +752,29 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
         # Buffer count matches the scratch allocation: single-slab runs
         # carry one slot (slot/nxt stay 0 — the prefetch branch never
         # fires), multi-slab runs double-buffer.
+        #
+        # The output is written IN PLACE (the field operands alias the
+        # outputs), so one rule orders HBM reads against writes: slab
+        # b+1's input DMA has COMPLETED before slab b's output DMA
+        # starts — slab b+1's window reads the last ``halo`` planes of
+        # slab b, and must see them before they are overwritten. Each
+        # slab's input is therefore waited at the end of the previous
+        # iteration (slab 0's in the prologue), after the compute the
+        # prefetch overlaps. Slab b-1's write and slab b+1's read are
+        # disjoint because bx >= halo (``_slab_fits``); a single slab
+        # reads everything before it writes anything.
         nio = 1 if nblocks == 1 else 2
         slab_io(0, jnp.int32(0), start=True)
+        slab_io(0, jnp.int32(0), start=False)
 
         def body(b, _):
             slot = lax.rem(b, nio)
             nxt = lax.rem(b + 1, nio)
+            more = b + 1 < nblocks
 
-            @pl.when(b + 1 < nblocks)
+            @pl.when(more)
             def _():
                 slab_io(nxt, b + 1, start=True)
-
-            slab_io(slot, b, start=False)
 
             @pl.when(b >= 2)
             def _():
@@ -761,6 +782,11 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
                     out_dma(slot, b - 2, tag).wait()
 
             compute(slot, b)
+
+            @pl.when(more)
+            def _():
+                slab_io(nxt, b + 1, start=False)
+
             for tag in range(n_f):
                 out_dma(slot, b, tag).start()
             return 0
@@ -842,6 +868,10 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
             jax.ShapeDtypeStruct((nx, ny, nz), dtype) for _ in range(n_f)
         ],
         scratch_shapes=scratch_shapes,
+        # Each field is updated in place: a launch's input is dead after
+        # it, so the result takes the operand's buffer and a loop carry
+        # needs no copy back (the x faces are separate, never aliased).
+        input_output_aliases={2 + i: i for i in range(n_f)},
         # Mosaic's default scoped-VMEM cap is well below the slab budget;
         # without an explicit limit L=256 f32 OOMs at kernel-stack
         # allocation even though the scratch fits physical VMEM.

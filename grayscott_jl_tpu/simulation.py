@@ -1212,9 +1212,6 @@ class Simulation:
                         return pin_block(
                             kernel_step(fields_c, step, faces_full)
                         )
-                    pairs = halo.exchange_x_slabs(
-                        fields_c, boundaries, AXIS_NAMES[0], dims[0], depth
-                    )
                     if overlap_on and fields_c[0].shape[0] >= 2 * depth:
                         # Split-phase round (docs/OVERLAP.md): the same
                         # 2-ppermute slab exchange is issued first, but
@@ -1234,6 +1231,23 @@ class Simulation:
                         self.overlap_applied = True
                         k = depth
                         nx = fields_c[0].shape[0]
+                        # The launch updates fields_c in place, so all
+                        # else the round reads of it (the slabs the
+                        # exchange sends, the bands' planes) is cut out
+                        # first: the 2k planes at each end, one 4k-plane
+                        # buffer per field, which the barrier keeps
+                        # ahead of the launch — else XLA keeps fields_c
+                        # alive past it with a full copy.
+                        ends = tuple(
+                            jnp.concatenate([f[:2 * k], f[nx - 2 * k:]])
+                            for f in fields_c
+                        )
+                        ends, fields_c = lax.optimization_barrier(
+                            (ends, fields_c)
+                        )
+                        pairs = halo.exchange_x_slabs(
+                            ends, boundaries, AXIS_NAMES[0], dims[0], k
+                        )
                         faces_z = tuple(
                             f for fs in halo.frozen_slabs(
                                 fields_c, boundaries, 0, k
@@ -1249,15 +1263,13 @@ class Simulation:
                         # band reads the arrived lo slab and the owned
                         # planes above it, the high band mirrors that.
                         jobs = (
-                            (tuple(f[:k] for f in fields_c),
-                             tuple(x for f, (lo, _hi) in zip(fields_c,
-                                                             pairs)
-                                   for x in (lo, f[k:2 * k])),
+                            (tuple(e[:k] for e in ends),
+                             tuple(x for e, (lo, _hi) in zip(ends, pairs)
+                                   for x in (lo, e[k:2 * k])),
                              0),
-                            (tuple(f[nx - k:] for f in fields_c),
-                             tuple(x for f, (_lo, hi) in zip(fields_c,
-                                                             pairs)
-                                   for x in (f[nx - 2 * k:nx - k], hi)),
+                            (tuple(e[3 * k:] for e in ends),
+                             tuple(x for e, (_lo, hi) in zip(ends, pairs)
+                                   for x in (e[2 * k:3 * k], hi)),
                              nx - k),
                         )
                         for body_f, faces_b, d_x in jobs:
@@ -1277,6 +1289,9 @@ class Simulation:
                                 for fi, bi in zip(interior, band)
                             ]
                         return pin_block(tuple(interior))
+                    pairs = halo.exchange_x_slabs(
+                        fields_c, boundaries, AXIS_NAMES[0], dims[0], depth
+                    )
                     faces_x = tuple(f for pr in pairs for f in pr)
                     return pin_block(pallas_stencil.fused_step(
                         fields_c, params, step_seeds(step), faces_x,

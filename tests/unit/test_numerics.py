@@ -225,6 +225,47 @@ def test_collective_counts():
     assert obs_xstats.collective_counts("add mul") == {}
 
 
+#: A step runner's HLO in miniature: two field parameters, a face, and
+#: every kind of copy the census must tell apart.
+_COPY_HLO = """\
+HloModule jit_gs_step, input_output_alias={ {0}: (0, {}, may-alias) }
+
+%body (p: (s32[], f32[64,128,128], f32[64,128,128])) -> (s32[], f32[64,128,128], f32[64,128,128]) {
+  %copy.21 = f32[64,128,128]{2,1,0:T(8,128)} copy(f32[64,128,128]{2,1,0:T(8,128)} %gte.1), metadata={op_name="copy(x)"}
+  %copy-start = (f32[64,128,128]{2,1,0:T(8,128)}, f32[64,128,128]{2,1,0:T(8,128)S(1)}, u32[]{:S(2)}) copy-start(%gte.2)
+  %copy-done = f32[64,128,128]{2,1,0:T(8,128)} copy-done(%copy-start)
+  %copy.3 = f32[2,128,128]{2,1,0:T(8,128)} copy(%face)
+  %copy.4 = f32[]{:T(128)} copy(%params_dt.1)
+  %copy.5 = bf16[64,128,128]{2,1,0:T(8,128)(2,1)} copy(%cast)
+  %fusion.9 = f32[64,128,128]{2,1,0:T(8,128)} fusion(%gte.1), kind=kLoop, metadata={op_name="copy(y)"}
+}
+
+ENTRY %main.6 (fields_0_.1: f32[64,128,128], fields_1_.1: f32[64,128,128], params_dt.1: f32[], faces_0_.1: f32[2,128,128]) -> (f32[64,128,128], f32[64,128,128]) {
+  ROOT %copy.30 = f32[64,128,128]{2,1,0} copy(%while)
+}
+"""
+
+
+def test_field_copies_census():
+    """Whole-field ``copy`` and ``copy-start`` count (here three: the
+    loop copy, the async pair's start, the ROOT copy); face-sized,
+    scalar and other-dtype copies, ``copy-done`` and a non-copy op
+    whose metadata says "copy(" do not."""
+    assert obs_xstats.field_copies(_COPY_HLO, 2) == 3
+    assert obs_xstats.field_copies("add mul", 2) == 0
+
+    class Compiled:
+        def as_text(self):
+            return _COPY_HLO
+
+    rec = obs_xstats.capture(Compiled(), name="r", compile_s=0.1,
+                             n_fields=2)
+    assert rec["field_copies"] == 3
+    assert "field_copies" not in obs_xstats.capture(
+        Compiled(), name="r", compile_s=0.1
+    )
+
+
 def test_capture_degrades_on_alien_compiled_object():
     class Alien:
         def cost_analysis(self):

@@ -8,6 +8,7 @@ configuration — a subsystem the reference does not have.
 """
 
 import numpy as np
+import pytest
 
 from grayscott_jl_tpu.ops import pallas_stencil as _ps
 
@@ -17,6 +18,14 @@ def _gs_spec():
     from grayscott_jl_tpu.ops import kernelgen
 
     return kernelgen.get_spec(grayscott.MODEL)
+
+
+def _races_found() -> bool:
+    """Whether the interpreter's race detector flagged the last
+    interpreted kernel (it prints each race and sets this flag)."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    return interpret_pallas_call.races.races_found
 
 
 def test_pipelined_kernel_has_no_dma_races():
@@ -45,6 +54,7 @@ def test_pipelined_kernel_has_no_dma_races():
         (u, v), params, seeds, spec=spec, use_noise=False,
         detect_races=True,
     )
+    assert not _races_found()
     want_u, want_v = pallas_stencil._xla_fallback(
         (u, v), params, seeds, None, spec=spec, use_noise=False
     )
@@ -89,6 +99,7 @@ def _chain_race_case(nx, ny, nz, k, offs, row, seed, monkeypatch,
         (u, v), params, seeds, faces, spec=spec, use_noise=True, fuse=k,
         offsets=offs, row=row, detect_races=True,
     )
+    assert not _races_found()
     monkeypatch.undo()
     want_u, want_v = pallas_stencil._xla_xchain_fallback(
         (u, v), params, seeds, faces, spec=spec, fuse=k, use_noise=True,
@@ -152,6 +163,7 @@ def test_single_buffer_whole_block_slab_has_no_dma_races():
         (u, v), params, seeds, spec=spec, use_noise=True, fuse=k,
         detect_races=True,
     )
+    assert not _races_found()
     us, vs = u, v
     for step in range(k):
         us, vs = pallas_stencil._xla_fallback(
@@ -164,3 +176,103 @@ def test_single_buffer_whole_block_slab_has_no_dma_races():
     np.testing.assert_allclose(
         np.asarray(v1), np.asarray(vs), rtol=1e-4, atol=2e-6
     )
+
+
+def _in_place_case(seed):
+    """Random 48x16x128 fields, fuse-3 x faces and params for the
+    in-place cases below."""
+    import jax
+    import jax.numpy as jnp
+
+    from grayscott_jl_tpu.config.settings import Settings
+    from grayscott_jl_tpu.models import grayscott
+
+    nx, ny, nz, k = 48, 16, 128, 3
+    s = Settings(L=3 * nx, Du=0.2, Dv=0.1, F=0.02, k=0.048, dt=1.0,
+                 noise=0.1, precision="Float32", backend="CPU",
+                 kernel_language="Pallas")
+    params = grayscott.Params.from_settings(s, jnp.float32)
+    key = jax.random.PRNGKey(seed)
+    fields = tuple(
+        jax.random.uniform(jax.random.fold_in(key, i), (nx, ny, nz))
+        for i in range(2)
+    )
+    faces = tuple(
+        jax.random.uniform(jax.random.fold_in(key, 2 + i), (k, ny, nz))
+        for i in range(4)
+    )
+    return fields, faces, params, k
+
+
+@pytest.mark.parametrize("x_chain", [False, True], ids=["chain", "x-chain"])
+def test_in_place_slabs_read_before_neighbour_writes(x_chain, monkeypatch):
+    """The kernel writes its result over its operand: slab b's output
+    DMA covers the last ``fuse`` planes slab b+1's input window reads.
+    At 3 slabs and fuse 3 the detector must find each such read ordered
+    before the write (the pipeline's wait rule), and the result must
+    equal bitwise the whole-block slab, which reads everything before it
+    writes anything — and the XLA program within the other tests'
+    tolerance (the CPU interpreter and XLA round a few cells 1 ulp
+    apart)."""
+    import jax.numpy as jnp
+
+    from grayscott_jl_tpu.ops import pallas_stencil
+
+    fields, faces, params, k = _in_place_case(31)
+    spec = _gs_spec()
+    seeds = jnp.asarray([9, 8, 7], jnp.int32)
+    kw = dict(spec=spec, use_noise=True, fuse=k)
+    if x_chain:
+        kw.update(offsets=jnp.asarray([48, 0, 0], jnp.int32),
+                  row=jnp.int32(144))
+    else:
+        faces = None
+
+    def run(bx, **extra):
+        monkeypatch.setenv("GS_BX", str(bx))
+        out = pallas_stencil.fused_step(fields, params, seeds, faces,
+                                        **kw, **extra)
+        monkeypatch.delenv("GS_BX")
+        return out
+
+    piped = run(16, detect_races=True)
+    assert not _races_found()
+    whole = run(48)
+    if x_chain:
+        want = pallas_stencil._xla_xchain_fallback(
+            fields, params, seeds, faces, **kw)
+    else:
+        want = fields
+        for step in range(k):
+            want = pallas_stencil._xla_fallback(
+                want, params, seeds.at[2].add(step), None, spec=spec,
+                use_noise=True,
+            )
+    for got, same, ref in zip(piped, whole, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref), rtol=1e-4, atol=2e-6
+        )
+
+
+def test_in_place_kernel_leaves_callers_input_alone(monkeypatch):
+    """Called eagerly on arrays the caller still holds (no donation),
+    the in-place kernel works on a copy: the caller's fields read the
+    same afterwards, and a second call gives the same result."""
+    import jax.numpy as jnp
+
+    from grayscott_jl_tpu.ops import pallas_stencil
+
+    fields, _faces, params, k = _in_place_case(37)
+    before = [np.asarray(f).copy() for f in fields]
+    monkeypatch.setenv("GS_BX", "16")
+    seeds = jnp.asarray([1, 2, 3], jnp.int32)
+    first = pallas_stencil.fused_step(fields, params, seeds,
+                                      spec=_gs_spec(), fuse=k)
+    for f, b in zip(fields, before):
+        np.testing.assert_array_equal(np.asarray(f), b)
+    again = pallas_stencil.fused_step(fields, params, seeds,
+                                      spec=_gs_spec(), fuse=k)
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not np.array_equal(np.asarray(first[0]), before[0])

@@ -149,3 +149,47 @@ def test_kernel_launch_is_stamped(one_chip, mosaic, variant, face_depth,
                    variant=variant)
     assert _kernel_metadata(hlo) == {"gs_kernel": want, "fuse": str(fuse)}
     assert f"%gs_{want}" in hlo
+
+
+@pytest.mark.parametrize("face_depth", [
+    pytest.param(None, id="one-chip-chain"),
+    pytest.param(2, id="x-chain"),
+])
+def test_chain_loop_updates_fields_in_place(one_chip, mosaic, face_depth):
+    """Three fuse-2 launches in a ``lax.fori_loop`` over donated fields,
+    as the step runner drives them: the launch aliases each field to
+    its output, so the loop carry needs no whole-field copy."""
+    from jax import lax
+
+    from grayscott_jl_tpu.obs import xstats
+
+    model = get_model("grayscott")
+    spec = kernelgen.get_spec(model)
+    settings = Settings(L=L, noise=0.1, precision="Float32",
+                        backend="TPU", kernel_language="Pallas")
+
+    def described(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shape = (L // 2, L, L) if face_depth else (L, L, L)
+    fields = (described(shape), described(shape))
+    params = jax.tree.map(lambda p: described(p.shape, p.dtype),
+                          model.make_params(settings, jnp.float32))
+    seeds = described((3,), jnp.int32)
+    faces = None
+    if face_depth:
+        faces = (described((face_depth,) + shape[1:]),) * 4
+
+    def run(fields, params, seeds, faces):
+        def body(i, carry):
+            return pallas_stencil.fused_step(
+                carry, params, seeds.at[2].add(2 * i), faces, spec=spec,
+                use_noise=True, fuse=2,
+            )
+
+        return lax.fori_loop(0, 3, body, tuple(fields))
+
+    hlo = jax.jit(run, donate_argnums=0).lower(
+        fields, params, seeds, faces).compile().as_text()
+    assert "output_to_operand_aliasing" in hlo
+    assert xstats.field_copies(hlo, 2) == 0
