@@ -2,8 +2,10 @@
 
 Everything cell-specific is data found by name (``BENCHMARK.json`` at the
 root, then ``configs/<config>.toml``, ``traffic/<traffic>.toml``,
-``limits/<cell>.json`` and ``metrics/<metric>.py`` here), so a later PR
-adds a cell, a configuration or a per-layer metric as new files. The
+``limits/<cell>.json``, ``metrics/<metric>.py`` and
+``reference/<model>.py`` here, the last by the configuration's
+``model``), so a later PR adds a cell, a configuration, a model's
+reference or a per-layer metric as new files. The
 functions take no notice of the platform; ``run.py`` refuses anything
 but a TPU before it calls them.
 """
@@ -49,14 +51,52 @@ def _by_name(entries, name, what):
     raise KeyError(f"no {what} named {name!r} in BENCHMARK.json")
 
 
+#: The configuration's stated precision -> the reference's dtype. A
+#: narrower one (BFloat16) needs a wider reference chosen on purpose.
+REFERENCE_DTYPES = {"Float32": "float32", "Float64": "float64"}
+
+
+def _load_file(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reference(table: dict, root: str = ROOT):
+    """``reference/<model>.py`` for the configuration ``table`` (its
+    ``model`` name or ``[model]`` table's ``name``, as the program
+    reads them; "grayscott" where it names none), and the dtype of its
+    stated ``precision`` (the program's default, Float64, where it
+    states none)."""
+    model = table.get("model", "grayscott")
+    if isinstance(model, dict):
+        model = model.get("name", "grayscott")
+    model = str(model).lower()
+    path = os.path.join(root, "perfbench", "reference", f"{model}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"model {model!r} has no plain reference: {path} is missing")
+    precision = table.get("precision", "Float64")
+    if precision not in REFERENCE_DTYPES:
+        raise ValueError(
+            f"precision {precision!r}: the reference runs only "
+            f"{sorted(REFERENCE_DTYPES)}")
+    return (_load_file(path, f"perfbench_reference_{model}"),
+            REFERENCE_DTYPES[precision])
+
+
 def load_cell(name: str, bench: dict, root: str = ROOT) -> dict:
-    """The cell ``name`` with its configuration, traffic and limits."""
+    """The cell ``name`` with its configuration, traffic, limits and
+    reference."""
     here = os.path.join(root, "perfbench")
     cell = dict(_by_name(bench["workloads"], name, "workload"))
     conf = _by_name(bench["configs"], cell["config"], "config")
     with open(os.path.join(root, conf["file"]), encoding="utf-8") as f:
         cell["config_text"] = f.read()
     cell["config_table"] = _toml.loads(cell["config_text"])
+    cell["reference"], cell["reference_dtype"] = load_reference(
+        cell["config_table"], root)
     with open(os.path.join(here, "traffic", f"{cell['traffic']}.toml"),
               "rb") as f:
         cell["traffic_spec"] = _toml.load(f)
@@ -87,11 +127,8 @@ def build_settings(cell: dict, backend=None):
 def load_reader(metric: str, root: str = ROOT):
     """``metrics/<metric>.py``'s ``read(trace, record)``."""
     path = os.path.join(root, "perfbench", "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_file(path, f"perfbench_metric_{metric.replace('.', '_')}"
+                      ).read
 
 
 # ---------------------------------------------------------------- spans
@@ -115,14 +152,15 @@ def span(name: str, spans: dict):
 def _max_abs_diff(a, b):
     import jax.numpy as jnp
 
-    d = jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))
+    wide = jnp.promote_types(a.dtype, b.dtype)
+    d = jnp.abs(a.astype(wide) - b.astype(wide))
     return jnp.where(jnp.all(jnp.isfinite(a)), jnp.max(d), jnp.inf)
 
 
 def widest_gaps(got, want, sharding) -> list:
     """Widest gap per field between host arrays ``got`` and the device
-    arrays ``want``, computed on the device (``inf`` where ``got`` is
-    not finite)."""
+    arrays ``want``, computed on the device in the wider of the two
+    dtypes (``inf`` where ``got`` is not finite)."""
     import jax
 
     fn = jax.jit(_max_abs_diff)
@@ -130,9 +168,16 @@ def widest_gaps(got, want, sharding) -> list:
             for g, w in zip(got, want)]
 
 
-def model_params(settings) -> dict:
-    return {"Du": settings.Du, "Dv": settings.Dv, "F": settings.F,
-            "k": settings.k, "dt": settings.dt, "noise": settings.noise}
+def reference(cell, settings, seed, devices, mesh_shape=(1, 1, 1)):
+    """The cell's reference, started at step 0: its model's module with
+    the settings' parameters, in the configuration's precision."""
+    from perfbench.reference.device import Reference
+
+    mod = cell["reference"]
+    ref = Reference(mod, settings.L, mod.params(settings), seed, devices,
+                    mesh_shape, cell["reference_dtype"])
+    ref.start()
+    return ref
 
 
 # ---------------------------------------------------------------- cells
@@ -191,14 +236,12 @@ class SimulationCell:
     def check(self, devices):
         """Widest gap per field, over every cell, between the compared
         round's fields and the reference at the same step."""
-        from perfbench.reference.device import Reference
-
-        ref = Reference(self.settings.L, model_params(self.settings),
-                        self.seed, devices, tuple(self.info["mesh_dims"]))
-        ref.start()
+        ref = reference(self.cell, self.settings, self.seed, devices,
+                        tuple(self.info["mesh_dims"]))
         ref.advance(self.compared_step)
         errs = widest_gaps(self.compared, ref.fields, ref.sharding)
-        return {f"{n}_max_abs_err": e for n, e in zip("uv", errs)}
+        return {f"{n}_max_abs_err": e
+                for n, e in zip(self.cell["reference"].FIELDS, errs)}
 
     def extra_record(self):
         return {}
@@ -250,6 +293,8 @@ class DriverCell:
         with span("warm_run", self.spans):
             self._run_once()
         self.info = describe(self.sim)
+        # The store's variables, as io/stream.py names them.
+        self.store_names = [n.upper() for n in self.sim.model.field_names]
         self.io_runs.clear()
 
     def window(self, seconds):
@@ -274,24 +319,23 @@ class DriverCell:
         last store, read back with the store reader, and the reference
         at the same step."""
         from grayscott_jl_tpu.io.bplite import BpReader
-        from perfbench.reference.device import Reference
 
         s = self.settings
-        ref = Reference(s.L, model_params(s), self.seed, devices[:1])
-        ref.start()
-        errs = [0.0, 0.0]
+        names = self.cell["reference"].FIELDS
+        ref = reference(self.cell, s, self.seed, devices[:1])
+        errs = [0.0] * len(names)
         reader = BpReader(s.output)
         try:
             nsteps = reader.num_steps()
             want_steps = s.steps // s.plotgap
-            if nsteps != want_steps:
-                log(f"store holds {nsteps} steps, expected {want_steps}")
-                return {"u_max_abs_err": float("inf"),
-                        "v_max_abs_err": float("inf")}
+            if (nsteps, len(self.store_names)) != (want_steps, len(names)):
+                log(f"store holds {nsteps} steps of {self.store_names}, "
+                    f"expected {want_steps} of {len(names)} fields")
+                return {f"{n}_max_abs_err": float("inf") for n in names}
             for i in range(nsteps):
                 ref.advance(s.plotgap)
                 want = [np.asarray(f) for f in ref.fields]
-                for fi, name in enumerate(("U", "V")):
+                for fi, name in enumerate(self.store_names):
                     got = reader.get(name, step=i)
                     d = np.abs(got.astype(np.float64) - want[fi])
                     e = float(np.max(d)) if np.all(np.isfinite(got)) \
@@ -300,7 +344,7 @@ class DriverCell:
         finally:
             reader.close()
             shutil.rmtree(self.tmp, ignore_errors=True)
-        return {f"{n}_max_abs_err": e for n, e in zip("uv", errs)}
+        return {f"{n}_max_abs_err": e for n, e in zip(names, errs)}
 
     def extra_record(self):
         return {"io_runs": list(self.io_runs)}

@@ -1,7 +1,8 @@
 """The program's own compile or cache-load seconds (``compile_s`` of
 obs/xstats) summed over the runners built in set-up. Layer: simulation
-set-up. Source: host clock. Reads ``record["executables"]``; nothing to
-read where the record has none or no runner was timed."""
+set-up. Source: program span (the program times its own compile or
+load). Reads ``record["executables"]``; nothing to read where the
+record has none or no runner was timed."""
 
 
 def read(trace, record):

@@ -18,8 +18,10 @@ bits. The key words are those of ``jax.random.PRNGKey(seed)`` with
 
 Every function takes the array namespace ``xp`` (``numpy`` or
 ``jax.numpy``), so the same arithmetic runs as a float64 NumPy oracle in
-the tests and as a float32 ``jax.numpy`` program on the chip. Nothing
-here imports ``grayscott_jl_tpu``.
+the tests and as a ``jax.numpy`` program on the chip (``device.py``),
+in the configuration's precision. As every reference module, it
+declares ``FIELDS``, ``params``, ``init`` and ``step``. Nothing here
+imports ``grayscott_jl_tpu``.
 """
 
 from __future__ import annotations
@@ -29,11 +31,25 @@ import numpy as np
 SEED_HALF_WIDTH = 6
 SEED_U, SEED_V = 0.25, 0.33
 U_BOUNDARY, V_BOUNDARY = 1.0, 0.0
+#: The fields in the program's order, as the checks name them.
+FIELDS = ("u", "v")
 
 
-def key_words(seed: int) -> tuple:
-    """Raw words of ``jax.random.PRNGKey(seed)`` with 64-bit mode off."""
-    return 0, int(seed) % 2**32
+def params(settings) -> dict:
+    """Du, Dv, F, k from the settings' ``[model]`` table, else their
+    flat keys (the program's order of precedence), then dt and noise."""
+    table = dict(getattr(settings, "model_params", None) or {})
+    out = {k: table.get(k, getattr(settings, k))
+           for k in ("Du", "Dv", "F", "k")}
+    out.update(dt=settings.dt, noise=settings.noise)
+    return out
+
+
+def key_words(seed: int, x64: bool = False) -> tuple:
+    """Raw words of ``jax.random.PRNGKey(seed)``, in 64-bit mode where
+    ``x64``."""
+    hi = (int(seed) >> 32) % 2**32 if x64 else 0
+    return hi, int(seed) % 2**32
 
 
 def _u32(xp, x):

@@ -53,6 +53,23 @@ def setup_seconds() -> float:
     return _AGE0 + time.perf_counter() - _T0
 
 
+def traced_record(cell, run, win, chips, device_kind) -> dict:
+    """What the per-layer readers read beside the trace."""
+    from perfbench import trace_reduce
+
+    record = run.extra_record()
+    record.update(
+        L=run.settings.L, chips=chips, steps=win["steps"],
+        window_s=win["wall_s"],
+        runner_compile_s=run.spans["construct"] + run.spans["first_round"],
+        executables=run.info["executables"],
+        peaks=trace_reduce.peaks(device_kind),
+        flops_per_cell_update=float(
+            cell["config_table"]["bench"]["flops_per_cell_update"]),
+    )
+    return record
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="perfbench")
     ap.add_argument("--workload", required=True)
@@ -107,11 +124,14 @@ def main(argv=None) -> int:
         jax.profiler.stop_trace()
     peak = max(d.memory_stats().get("peak_bytes_in_use", 0)
                for d in devices)
-    record = run.extra_record()
+    if args.trace:
+        record = traced_record(cell, run, win, chips, devices[0].device_kind)
     run.free()
     gc.collect()
 
+    t_check = time.perf_counter()
     checks = run.check(devices)
+    H.log(f"check {time.perf_counter() - t_check:.3f} s")
     correct, judged = H.judge(checks, cell["limits"])
 
     L = settings.L
@@ -123,14 +143,6 @@ def main(argv=None) -> int:
     if args.trace:
         red = trace_reduce.load(trace_dir, n_devices=chips)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        record.update(
-            L=L, chips=chips, steps=win["steps"], window_s=win["wall_s"],
-            runner_compile_s=run.spans["construct"]
-            + run.spans["first_round"],
-            peaks=trace_reduce.peaks(devices[0].device_kind),
-            flops_per_cell_update=float(
-                cell["config_table"]["bench"]["flops_per_cell_update"]),
-        )
         for m in cell["per_layer"]:
             value = H.load_reader(m["name"])(red, record)
             if value is not None:

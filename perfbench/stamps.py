@@ -11,10 +11,10 @@ driver's phases. In a trace without them (one recorded before the
 program stamped its work) every function here finds nothing, and the
 readers return None.
 
-``Reduced`` (``trace_reduce.py``) keeps the harness's ``perfbench.*``
-host spans only; :func:`load_spans` reads the program's ``gs.*`` spans
-from the same ``.xplane.pb``. A reader of idle time per span looks for
-them on ``trace.program_spans``.
+``trace_reduce.from_profile`` puts the program's ``gs.*`` host spans
+(:func:`collect_spans`) on ``Reduced.program_spans``, where a reader of
+idle time per span looks for them; :func:`load_spans` reads them alone
+from a kept trace directory.
 """
 
 from __future__ import annotations
@@ -89,8 +89,7 @@ def idle_us_per_round(trace, name: str) -> float | None:
     spans named ``name`` (``gs.iterate``, ``gs.sync``), summed, over the
     number of such spans in the window, averaged over the chips, in us.
     None where the trace has no such span in its window."""
-    spans = getattr(trace, "program_spans", None) or []
-    mine = T.clip([(s, e) for n, s, e in spans if n == name],
+    mine = T.clip([(s, e) for n, s, e in trace.program_spans if n == name],
                   trace.lo, trace.hi)
     if not mine or not trace.busy:
         return None

@@ -8,7 +8,9 @@ the host side). ``load(dir, n_devices)`` reads the ``.xplane.pb`` with
 * the device ops of each chip, with start, end, program (HLO module)
   and the bytes of their operands and results from the HLO shapes;
 * the union of busy intervals per chip, inside the traced window;
-* the idle gaps, each named by the innermost harness span it falls in.
+* the idle gaps, each named by the innermost harness span it falls in;
+* the program's own ``gs.*`` host spans (``stamps.collect_spans``), on
+  ``program_spans``.
 
 The traced window runs from the start of the first window span
 (``perfbench.round`` or ``perfbench.run_once``) to the end of the last.
@@ -180,8 +182,9 @@ class Op:
 class Reduced:
     """Device ops per chip, host spans and the traced window (ns)."""
 
-    def __init__(self, ops_by_device, spans):
+    def __init__(self, ops_by_device, spans, program_spans=()):
         self.spans = spans  # [(name, start, end)], host clock
+        self.program_spans = list(program_spans)  # the same, gs.*
         wins = [(s, e) for n, s, e in spans if n in WINDOW_SPANS]
         if not wins:
             raise ValueError("trace holds no window span")
@@ -277,6 +280,8 @@ def _module_of(modules, starts, t):
 
 
 def from_profile(pd, n_devices=None) -> Reduced:
+    from perfbench.stamps import collect_spans  # stamps imports this
+
     ops_by_device = {}
     spans = []
     for plane in pd.planes:
@@ -299,7 +304,7 @@ def from_profile(pd, n_devices=None) -> Reduced:
                 for ev in line.events:
                     if ev.name.startswith("perfbench."):
                         spans.append((ev.name, ev.start_ns, ev.end_ns))
-    return Reduced(ops_by_device, spans)
+    return Reduced(ops_by_device, spans, collect_spans(pd))
 
 
 def load(trace_dir: str, n_devices=None) -> Reduced:

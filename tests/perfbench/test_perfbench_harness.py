@@ -2,11 +2,15 @@
 
 Each cell's code path runs through the harness's own functions (the
 command itself refuses the CPU): a sound run comes out correct; the
-control (the program's ``bf16_f32acc`` posture) and each fault the cell
-can have, planted underneath the timed path, come out not correct.
-Also: BENCHMARK.json keeps the contract's shape, new cells,
-configurations and metric readers are found by name as new files, and
-``run.py`` exits non-zero with no result where there is no TPU.
+control (the program's ``bf16_f32acc`` posture, or Float32 where the
+configuration states Float64) and each fault the cell can have, planted
+underneath the timed path, come out not correct. Cells of another model
+(``heat``) and of another precision (Float64) are added as files under
+a copy of ``perfbench/`` alone, and a model with no reference module is
+refused as the cell is loaded. Also: BENCHMARK.json keeps the contract's
+shape, new cells, configurations and metric readers are found by name
+as new files, and ``run.py`` exits non-zero with no result where there
+is no TPU.
 """
 
 import json
@@ -38,7 +42,12 @@ TINY = {
                                  "kernel_language": "Plain"}),
     # Long enough for the control to pass the cell's limit (2e-2).
     "gs1024-f32-2x2x1": (16, 100, {}),
+    "heat16": (16, 10, {}),
+    "gs16-f64": (16, 10, {}),
 }
+#: The control where it is not the bf16_f32acc posture: the program one
+#: precision below the configuration's, the reference at the stated one.
+CONTROL = {"gs16-f64": {"precision": "Float32"}}
 
 
 #: The upstream tutorial cell, staged as files under perfbench/ but not
@@ -53,6 +62,96 @@ STAGED = {
 }
 
 
+#: A reference module for the program's ``heat`` model, written from
+#: models/heat.py's declaration (one field T, ghost 0, T=1 on the seed
+#: cube [L/2-6, L/2+6]^3, ``T_t = D*lap(T) + noise*U(-1, 1)``, D 0.2 by
+#: default) with the Gray-Scott reference's stencil and noise stream.
+HEAT_REFERENCE = '''
+from perfbench.reference.grayscott import laplacian, unit_noise
+
+FIELDS = ("T",)
+
+
+def params(settings):
+    table = dict(settings.model_params or {})
+    return {"D": table.get("D", 0.2), "dt": settings.dt,
+            "noise": settings.noise}
+
+
+def init(xp, L, dtype):
+    idx = xp.arange(L)
+    inside = (idx >= L // 2 - 6) & (idx <= L // 2 + 6)
+    cube = (inside[:, None, None] & inside[None, :, None]
+            & inside[None, None, :])
+    return (xp.where(cube, 1.0, 0.0).astype(dtype),)
+
+
+def step(xp, t, step_index, params, key, L=None):
+    c = {k: xp.asarray(params[k], dtype=t.dtype) for k in params}
+    dt_ = c["D"] * laplacian(xp, t, 0.0)
+    if params["noise"] != 0.0:
+        pm1 = unit_noise(xp, key, step_index, t.shape,
+                         row=t.shape[2] if L is None else L)
+        dt_ = dt_ + c["noise"] * pm1.astype(t.dtype)
+    return ((t + dt_ * c["dt"]).astype(t.dtype),)
+'''
+_GS = """Du = 0.2
+Dv = 0.1
+F = 0.02
+k = 0.048
+dt = 1.0
+noise = 0.1
+backend = "TPU"
+kernel_language = "XLA"
+"""
+#: Files a later PR would add for a heat cell, a Float64 cell and a cell
+#: of a model with no reference (brusselator), and nothing else.
+FILES_ONLY = {
+    "perfbench/reference/heat.py": HEAT_REFERENCE,
+    "perfbench/configs/heat-L16.toml": (
+        'L = 16\ndt = 1.0\nnoise = 0.1\nprecision = "Float32"\n'
+        'kernel_language = "XLA"\n[model]\nname = "heat"\nD = 0.2\n'),
+    # Sound runs read 2.4e-7 to 3.6e-7 over five seeds (float32 rounding:
+    # the program folds the stencil's 1/6 into a multiply); the altered
+    # answer reads 5.8e-2, the unchanged state 0.89.
+    "perfbench/limits/heat16.json": '{"T_max_abs_err": 1e-5}',
+    "perfbench/configs/gs-L16-f64.toml": (
+        "L = 16\n" + _GS + 'precision = "Float64"\n'),
+    # Over five seeds sound Float64 runs read 4.4e-16 (u) and 5.6e-16 to
+    # 6.7e-16 (v), rounding in the 16th digit; the program at Float32
+    # reads 2.0e-7 to 2.6e-7. The limit lies 1,500 times above the one
+    # and 200,000 times below the other.
+    "perfbench/limits/gs16-f64.json": (
+        '{"u_max_abs_err": 1e-12, "v_max_abs_err": 1e-12}'),
+    "perfbench/configs/bruss-L16.toml": (
+        'L = 16\nprecision = "Float32"\n[model]\nname = "brusselator"\n'),
+    "perfbench/limits/bruss16.json": '{"u_max_abs_err": 1e-5}',
+}
+FILES_ONLY_ENTRIES = {
+    "configs": [{"name": n, "source": "x", "file": f"perfbench/configs/{n}"
+                 ".toml", "reduced": [], "why": "x"}
+                for n in ("heat-L16", "gs-L16-f64", "bruss-L16")],
+    "workloads": [{"name": w, "config": c, "traffic": "rounds200",
+                   "chips": 1, "why": "x"}
+                  for w, c in (("heat16", "heat-L16"),
+                               ("gs16-f64", "gs-L16-f64"),
+                               ("bruss16", "bruss-L16"))],
+}
+
+
+def _files_only_tree(tmp_path):
+    """A copy of perfbench/ plus FILES_ONLY, and the BENCHMARK.json that
+    names them: (root, bench)."""
+    shutil.copytree(os.path.join(ROOT, "perfbench"), tmp_path / "perfbench")
+    for rel, text in FILES_ONLY.items():
+        (tmp_path / rel).write_text(text)
+    b = _bench()
+    for key, entries in FILES_ONLY_ENTRIES.items():
+        b[key] = b[key] + entries
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    return str(tmp_path), H.load_benchmark(str(tmp_path))
+
+
 def _bench():
     return H.load_benchmark(ROOT)
 
@@ -64,14 +163,15 @@ def _bench_with_staged():
     return b
 
 
-def _run(name, patch=None):
+def _run(name, patch=None, root=ROOT, bench=None, control=None):
     """One run of the cell ``name`` at its tiny size, through set-up,
-    window and comparison; returns (correct, checks)."""
-    cell = H.load_cell(name, _bench_with_staged())
+    window and comparison, with ``control``'s settings for the program
+    alone; returns (correct, checks)."""
+    cell = H.load_cell(name, bench or _bench_with_staged(), root)
     L, rounds, over = TINY[name]
     s = H.build_settings(cell, backend="CPU")
     s.L = L
-    for k, v in over.items():
+    for k, v in dict(over, **(control or {})).items():
         setattr(s, k, v)
     if rounds:
         cell["traffic_spec"]["round_steps"] = rounds
@@ -237,15 +337,30 @@ CASES = [
     ("gs1024-f32-2x2x1", "sound"), ("gs1024-f32-2x2x1", "control"),
     ("gs1024-f32-2x2x1", "unchanged"), ("gs1024-f32-2x2x1", "half"),
     ("gs1024-f32-2x2x1", "altered"), ("gs1024-f32-2x2x1", "exchange"),
+    # Added as files only (FILES_ONLY).
+    ("heat16", "sound"), ("heat16", "unchanged"), ("heat16", "altered"),
+    ("gs16-f64", "sound"), ("gs16-f64", "control"),
+    ("bruss16", "refused"),
 ]
 
 
 @pytest.mark.parametrize("name,case", CASES,
                          ids=[f"{n}-{c}" for n, c in CASES])
 def test_correct_separates_sound_runs_from_control_and_faults(
-        monkeypatch, name, case):
-    patch = None
-    if case == "control":
+        monkeypatch, tmp_path, name, case):
+    root, bench = ROOT, None
+    if any(w["name"] == name for w in FILES_ONLY_ENTRIES["workloads"]):
+        root, bench = _files_only_tree(tmp_path)
+    if case == "refused":
+        missing = os.path.join(root, "perfbench", "reference",
+                               "brusselator.py")
+        with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+            H.load_cell(name, bench, root)
+        return
+    patch = control = None
+    if case == "control" and name in CONTROL:
+        control = CONTROL[name]
+    elif case == "control":
         monkeypatch.setenv("GS_COMPUTE_PRECISION", "bf16_f32acc")
     elif case == "unchanged":
         _unchanged(monkeypatch)
@@ -255,5 +370,5 @@ def test_correct_separates_sound_runs_from_control_and_faults(
         patch = lambda L: _answer_altered(monkeypatch, L)  # noqa: E731
     elif case == "exchange":
         _exchange_left_out(monkeypatch)
-    correct, checks = _run(name, patch)
+    correct, checks = _run(name, patch, root, bench, control)
     assert correct == (case == "sound"), checks

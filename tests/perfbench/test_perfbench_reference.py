@@ -5,9 +5,12 @@ The reference imports nothing of the program; these tests are where the
 two meet: the NumPy transcription equals ``tests/oracle.py`` without
 noise, its noise equals the program's position-keyed stream bit for
 bit, and the program's XLA path agrees with it with noise on, on one
-device and on a 2x2x1 mesh of virtual devices.
+device and on a 2x2x1 mesh of virtual devices. The device reference
+runs in the precision it is given, and its float32 trajectory is pinned
+bit for bit.
 """
 
+import hashlib
 import os
 import sys
 
@@ -82,15 +85,53 @@ def test_program_agrees_with_reference_noise_on(n_devices):
     assert np.ptp(u) > 0.05
 
 
-@pytest.mark.parametrize("mesh", [(1, 1, 1), (2, 2, 1)])
-def test_device_reference_agrees_with_numpy(mesh):
+@pytest.mark.parametrize("mesh,dtype,tol", [
+    ((1, 1, 1), "float32", 2e-6), ((2, 2, 1), "float32", 2e-6),
+    # The same arithmetic in float64: only the order of rounding differs.
+    ((1, 1, 1), "float64", 1e-14), ((2, 2, 1), "float64", 1e-14),
+])
+def test_device_reference_agrees_with_numpy(mesh, dtype, tol):
     L, n = 16, 20
-    r = Reference(L, PARAMS, SEED, jax.devices(), mesh)
+    r = Reference(ref, L, PARAMS, SEED, jax.devices(), mesh, dtype)
     r.start()
     r.advance(8)
     r.advance(n - 8)
     u, v = (np.asarray(f) for f in r.fields)
     ru, rv = ref.run_numpy(L, n, PARAMS, SEED)
-    assert u.dtype == np.float32
-    assert np.max(np.abs(u - ru)) < 2e-6
-    assert np.max(np.abs(v - rv)) < 2e-6
+    assert u.dtype == v.dtype == np.dtype(dtype)
+    assert np.max(np.abs(u - ru)) < tol
+    assert np.max(np.abs(v - rv)) < tol
+
+
+#: SHA-256 of the float32 reference's u and v bytes after 40 steps at
+#: L=16 (PARAMS, SEED), as the reference made them before it took its
+#: model and precision as arguments: existing cells compare against the
+#: same fields to the last bit.
+PINNED_F32 = (
+    "5d3e24729a0a14f534a1951e5e685413b2c13b85ebfa7037535a94d127457340",
+    "247fc8c8f63161be5bd4c6913302e109cdfcdce7afa4bb97a487276d8520708f",
+)
+
+
+@pytest.mark.parametrize("mesh", [(1, 1, 1), (2, 2, 1)])
+def test_float32_reference_is_pinned_bitwise(mesh):
+    r = Reference(ref, 16, PARAMS, SEED, jax.devices(), mesh, "float32")
+    r.start()
+    r.advance(40)
+    got = tuple(hashlib.sha256(np.asarray(f).tobytes()).hexdigest()
+                for f in r.fields)
+    assert got == PINNED_F32
+
+
+def test_key_words_follow_64_bit_mode():
+    """The program's key for a seed past 2**32 differs in 64-bit mode;
+    below it the two agree."""
+    big = 2**32 + 5
+    jax.config.update("jax_enable_x64", False)
+    assert ref.key_words(big) == tuple(
+        int(w) for w in np.asarray(jax.random.PRNGKey(big)))
+    jax.config.update("jax_enable_x64", True)
+    for seed in (big, SEED):
+        assert ref.key_words(seed, x64=True) == tuple(
+            int(w) for w in np.asarray(jax.random.PRNGKey(seed)))
+    assert ref.key_words(SEED, x64=True) == ref.key_words(SEED)

@@ -2,7 +2,9 @@
 (perfbench/stamps.py): kernel launches and chain glue of the ``gs_step``
 program, device idle inside the program's ``gs.iterate`` / ``gs.sync``
 spans, and the program's own runner load time. Made-up traces for the
-arithmetic; the committed chip traces for what a real one reads.
+arithmetic; the committed chip traces for what a real one reads, with
+the program's spans as ``trace_reduce.from_profile`` collects them and
+the record as ``run.py`` builds it.
 """
 
 import json
@@ -177,9 +179,7 @@ def parent_round():
     with open(os.path.join(d, "record.json"), encoding="utf-8") as f:
         record = json.load(f)
     record["peaks"] = T.peaks("TPU v5 lite")
-    red = T.load(d, n_devices=1)
-    red.program_spans = stamps.load_spans(d)
-    return red, record
+    return T.load(d, n_devices=1), record
 
 
 def test_unstamped_trace_reads_none_for_every_new_metric(parent_round):
@@ -199,9 +199,7 @@ def stamped_round():
     with open(os.path.join(d, "record.json"), encoding="utf-8") as f:
         record = json.load(f)
     record["peaks"] = T.peaks("TPU v5 lite")
-    red = T.load(d, n_devices=1)
-    red.program_spans = stamps.load_spans(d)
-    return red, record
+    return T.load(d, n_devices=1), record
 
 
 def test_stamped_trace_names_the_step_program_and_its_launches(
@@ -239,3 +237,53 @@ def test_stamped_trace_reads_every_new_metric(stamped_round):
     idle_us = (red.window_s - red.busy_s) * 1e6
     assert (got["dispatch_idle_us_per_round"]
             + got["sync_idle_us_per_round"]) <= idle_us
+
+
+def test_from_profile_collects_the_programs_spans():
+    """The reduced trace carries the ``gs.*`` spans that load_spans
+    reads alone from the same file; a trace without them carries none."""
+    d = os.path.join(TESTDATA, "gs256-1round-spans")
+    red = T.load(d, n_devices=1)
+    assert red.program_spans == stamps.load_spans(d)
+    assert {n for n, _, _ in red.program_spans} >= {"gs.iterate", "gs.sync"}
+    assert T.load(os.path.join(TESTDATA, "gs256-1round"),
+                  n_devices=1).program_spans == []
+
+
+class _TracedRun:
+    """What run.py's traced record takes from a cell's run."""
+
+    def __init__(self, rec):
+        self.settings = type("S", (), {"L": rec["L"]})()
+        self.spans = {"construct": rec["runner_compile_s"],
+                      "first_round": 0.0}
+        self.info = {"executables": rec["executables"]}
+
+    def extra_record(self):
+        return {}
+
+
+@pytest.mark.parametrize("cell", ["gs256-f32-compute", "gs1024-f32-2x2x1"])
+def test_every_listed_metric_reads_from_the_runs_record(cell):
+    """Each per-layer metric BENCHMARK.json lists for the cell reads a
+    number from the committed stamped trace and the record that run.py
+    builds from a run (the three host-dispatch and set-up readers
+    among them); exchange_exposed alone needs more than one chip."""
+    from perfbench import run as R
+
+    d = os.path.join(TESTDATA, "gs256-1round-spans")
+    with open(os.path.join(d, "record.json"), encoding="utf-8") as f:
+        rec = json.load(f)
+    c = H.load_cell(cell, H.load_benchmark(ROOT), ROOT)
+    record = R.traced_record(
+        c, _TracedRun(rec), {"steps": rec["steps"], "wall_s": 1.0},
+        rec["chips"], "TPU v5 lite")
+    assert record["executables"] == rec["executables"]
+    red = T.load(d, n_devices=1)
+    got = {m["name"]: _read(m["name"], red, record) for m in c["per_layer"]}
+    assert {"dispatch_idle_us_per_round", "sync_idle_us_per_round",
+            "runner_load_s"} <= set(got)
+    for name, value in got.items():
+        if name != "exchange_exposed_us_per_step":
+            assert value is not None and value > 0, name
+    assert got["runner_load_s"] == pytest.approx(3.884333)
