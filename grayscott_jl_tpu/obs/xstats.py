@@ -7,7 +7,9 @@ and bytes, ``memory_analysis()`` buffer sizes, the HLO collective
 census (how many collective-permutes/all-reduces the schedule really
 carries — the number the HLO regression tests and the icimodel
 calibration loop reason about), the whole-field copy census (loop-carry
-copies an out-of-place kernel would force), compile wall time, and the
+copies an out-of-place kernel would force), the whole-field relayout
+census (pads, slices and concatenates of field size around a launch),
+compile wall time, and the
 persistent compilation cache outcome (hit/miss) per executable.
 Records land in three places at capture time: ``sim.executables``
 (merged into the RunStats ``executables`` section by the driver), one
@@ -44,6 +46,7 @@ __all__ = [
     "collective_counts",
     "cache_listing",
     "field_copies",
+    "field_relayouts",
     "cache_outcome",
     "instrument_compile",
     "publish",
@@ -127,6 +130,73 @@ def field_copies(hlo_text: str, n_fields: int) -> int:
     return count
 
 
+#: An HLO instruction: optional ROOT, name, result type, opcode.
+_INSTR_RE = re.compile(
+    r"^\s*(ROOT\s+)?%\S+\s=\s(.+?)\s([a-z][a-z0-9\-]*)\(", re.MULTILINE
+)
+
+#: A computation header, ``%name (params) -> type {`` or ``ENTRY ...``.
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%(\S+)\s.*\{\s*$")
+
+#: Opcodes that move a whole array into a new layout.
+_RELAYOUT_OPS = ("pad", "slice", "concatenate")
+
+
+def _elements(type_text: str) -> int:
+    """Element count of an array result type; 0 for a tuple."""
+    t = _ARRAY_TYPE_RE.match(type_text)
+    if t is None:
+        return 0
+    n = 1
+    for d in t.group(2).split(","):
+        n *= int(d) if d else 1
+    return n
+
+
+def field_relayouts(hlo_text: str, n_fields: int) -> int:
+    """``pad``, ``slice`` and ``concatenate`` results holding at least a
+    field block's element count (the first ``n_fields`` ENTRY
+    parameters), whether the op stands alone or is the root of a
+    fusion: each reads and writes a whole field to move it into a new
+    layout — the y pad before an xy-chain launch, the slice after it.
+    In-place ``dynamic-update-slice`` and face-sized ops are not
+    counted, nor ops inside a fusion other than its root."""
+    sizes = [
+        _elements(f"{dt}[{dims}]")
+        for dt, dims in _entry_param_types(hlo_text)[:n_fields]
+    ]
+    if not sizes:
+        return 0
+    field = min(sizes)
+    roots: dict = {}
+    body: list = []  # (computation, opcode, type, called) of each op
+    comp = None
+    for line in hlo_text.splitlines():
+        c = _COMP_RE.match(line)
+        if c is not None:
+            comp = c.group(1)
+            continue
+        m = _INSTR_RE.match(line)
+        if m is None:
+            continue
+        is_root, type_text, op = m.groups()
+        called = re.search(r"\bcalls=%([\w.\-]+)", line)
+        body.append((comp, op, type_text,
+                     called.group(1) if called else None))
+        if is_root:
+            roots[comp] = op
+    fused = {called for _, op, _, called in body if op == "fusion"}
+    count = 0
+    for comp, op, type_text, called in body:
+        if comp in fused or _elements(type_text) < field:
+            continue
+        if op == "fusion":
+            op = roots.get(called)
+        if op in _RELAYOUT_OPS:
+            count += 1
+    return count
+
+
 def cache_listing(path: Optional[str]) -> Optional[frozenset]:
     """Entries of the persistent compile cache directory, or None when
     no cache is armed / the directory is unreadable."""
@@ -163,7 +233,7 @@ def capture(compiled, *, name: str, compile_s: float,
     """One executable's analytics record, from a ``jax`` AOT-compiled
     object. Every query is individually best-effort. ``n_fields``
     (the step runner's leading field arguments) arms the
-    ``field_copies`` census."""
+    ``field_copies`` and ``field_relayouts`` censuses."""
     rec = {"name": name, "compile_s": round(compile_s, 6)}
     if extra:
         rec.update(extra)
@@ -210,6 +280,7 @@ def capture(compiled, *, name: str, compile_s: float,
         rec["collectives"] = collective_counts(hlo)
         if n_fields:
             rec["field_copies"] = field_copies(hlo, n_fields)
+            rec["field_relayouts"] = field_relayouts(hlo, n_fields)
         # Mosaic (Pallas-on-TPU) kernels in the program: 0 means the
         # step ran no fused kernel (XLA path, or interpret mode).
         rec["tpu_custom_calls"] = hlo.count(

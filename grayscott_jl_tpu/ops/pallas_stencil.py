@@ -295,18 +295,21 @@ def mosaic_gate_reason(local, itemsize: int):
     of the dispatch-level gates in :func:`fused_step` (f64 fallback,
     128-lane tiling of the z extent) shared with the ICI model's Auto
     dispatch (``parallel/icimodel.py``) — the model must never promise
-    a schedule the kernel would silently decline. The y-sublane gate is
-    not here: chain operands arrive y-extended and sublane-rounded, and
-    a 128-aligned cubic block satisfies it by construction. Model-side
-    feasibility (can the reaction be inlined at all?) is
-    ``kernelgen.generation_gate_reason`` — orthogonal to this shape
-    gate."""
-    nz = local[2]
+    a schedule the kernel would silently decline. Every form DMAs the
+    owned rows whole, so the local y extent must be a whole number of
+    sublane tiles as well. Model-side feasibility (can the reaction be
+    inlined at all?) is ``kernelgen.generation_gate_reason`` —
+    orthogonal to this shape gate."""
+    ny, nz = local[1], local[2]
+    sublane = 16 if itemsize == 2 else 8
     if itemsize == 8:
         return "float64 runs the Pallas kernel's XLA fallback on TPU"
     if nz % 128:
         return (f"local z extent {nz} misses Mosaic's 128-lane "
                 "alignment; the Pallas kernel would fall back to XLA")
+    if ny % sublane:
+        return (f"local y extent {ny} misses Mosaic's {sublane}-row "
+                "sublane tile; the Pallas kernel would fall back to XLA")
     return None
 
 
@@ -327,17 +330,24 @@ def max_feasible_fuse(nx: int, ny: int, nz: int, itemsize: int,
     return 0
 
 
+def y_halo_rows(depth: int, sublane: int = 8) -> int:
+    """Rows of each xy-chain y-halo operand at chain depth ``depth``:
+    the ``depth`` exchanged rows rounded up to whole sublane tiles, so
+    the owned rows and both halos land on tile boundaries in VMEM."""
+    return depth + (-depth) % sublane
+
+
 def max_feasible_fuse_ypad(nx: int, ny: int, nz: int, itemsize: int,
                            fuse: int, sublane: int = 8,
                            mid_itemsize: int = None,
                            n_fields: int = 2) -> int:
-    """:func:`max_feasible_fuse` for the xy-chain mode, where the
-    operand arrives y-extended: depth k widens every plane to
-    ``ny + 2k`` rows rounded up to the sublane tile, so feasibility
-    must be judged on the padded shape."""
+    """:func:`max_feasible_fuse` for the xy-chain mode, where the kernel
+    assembles y-extended planes in VMEM: depth k widens every plane to
+    ``ny + 2 * y_halo_rows(k)`` rows, so feasibility must be judged on
+    that shape (the output scratch holds only the ``ny`` owned rows,
+    so the check is conservative by its halo rows)."""
     for k in range(fuse, 0, -1):
-        ny_ext = ny + 2 * k
-        ny_ext += (-ny_ext) % sublane
+        ny_ext = ny + 2 * y_halo_rows(k, sublane)
         if pick_block_planes(nx, ny_ext, nz, itemsize, k,
                              mid_itemsize=mid_itemsize,
                              n_fields=n_fields) > 0:
@@ -414,7 +424,7 @@ def _shifted(block, axis, shift, edge_value, masks):
 
 
 def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
-                 with_faces, fuse, mid_bf16=False):
+                 with_faces, fuse, mid_bf16=False, y_halo=0):
     """Build the fused single-program kernel body for ``spec``'s model;
     see module docstring. The pipeline is model-independent; the stage
     compute TRACE-INLINES ``spec.reaction`` over the window interiors
@@ -429,27 +439,37 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
     mid-stage out-of-domain pinning keyed on GLOBAL x coordinates so
     interior shards recompute the neighbor ring instead of freezing it.
 
+    ``y_halo > 0`` is the xy-chain form of the x-chain mode: ``ny`` is
+    the VMEM plane height, the fields hold only the ``ny - 2*y_halo``
+    owned rows, and per-field (lo, hi) y-halo operands of ``y_halo``
+    rows fill the rows above and below them in VMEM. Only the owned
+    rows are written back.
+
     Ref order, for an n-field model (mid scratch present only when
-    ``fuse >= 2``):
+    ``fuse >= 2``; ``ny_own = ny - 2*y_halo``):
       params(SMEM f32[n_params]; f64 for f64 fields — never bf16,
       Mosaic SMEM support for bf16 scalars is shaky),
-      seeds(SMEM i32[7] = key lo, key hi, step, x/y/z global offset,
-      global row length L — the position-keyed noise coordinates),
-      f_0 .. f_{n-1} (ANY/HBM, (nx, ny, nz)),
+      seeds(SMEM i32[7] = key lo, key hi, step, x/y/z global offset
+      of the VMEM plane, global row length L — the position-keyed noise
+      coordinates),
+      f_0 .. f_{n-1} (ANY/HBM, (nx, ny_own, nz)),
       [f_0_xlo, f_0_xhi, .., f_{n-1}_xhi (ANY, (fuse, ny, nz)),
        fuse==1 only: per-field y faces (VMEM, (nx, 1, nz)),
                      per-field z faces (VMEM, (nx, ny, 1))],
+      [y_halo only: f_0_ylo, f_0_yhi, .., f_{n-1}_yhi
+                    (ANY, (nx, y_halo, nz))],
       f_0_out .. f_{n-1}_out (ANY/HBM, aliasing f_0 .. f_{n-1}),
       scratch: in_0 .. in_{n-1} (VMEM (2, bx+2*fuse, ny, nz)),
                [mid_0 .. mid_{n-1} (VMEM (nbuf, bx+2(fuse-1), ny, nz))],
-               out_0 .. out_{n-1} (VMEM (2, bx, ny, nz)),
+               out_0 .. out_{n-1} (VMEM (2, bx, ny_own, nz)),
                in_sems (DMA (2, n)), out_sems (DMA (2, n)),
-               [face_sems (DMA (2, n, 2))]
+               [face_sems (DMA (2, n, 2))], [halo_sems (DMA (2, n, 2))]
     """
     halo = fuse
     win_n = bx + 2 * halo
     x_chain = with_faces and fuse >= 2
     n_f = spec.n_fields
+    ny_own = ny - 2 * y_halo
 
     def kernel(params, seeds, *rest):
         rest = list(rest)
@@ -469,12 +489,16 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
                 zf = take(2 * n_f)
                 y_faces = [(yf[2 * i], yf[2 * i + 1]) for i in range(n_f)]
                 z_faces = [(zf[2 * i], zf[2 * i + 1]) for i in range(n_f)]
+        if y_halo:
+            yh = take(2 * n_f)
+            y_halos = [(yh[2 * i], yh[2 * i + 1]) for i in range(n_f)]
         field_outs = take(n_f)
         ins = take(n_f)
         mids = take(n_f) if fuse >= 2 else None
         out_scr = take(n_f)
         in_sems, out_sems = take(2)
-        face_sems = rest[0] if with_faces else None
+        face_sems = take(1)[0] if with_faces else None
+        halo_sems = take(1)[0] if y_halo else None
 
         # cdt == dtype except bf16, which computes in f32 (_compute_dtype).
         cdt = _compute_dtype(dtype)
@@ -502,6 +526,11 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
             the fill just keeps scratch deterministic). Descriptors are
             constructed lazily inside their branch — an unused
             descriptor is an error.
+
+            In the xy-chain form (``y_halo``) the field's owned rows land
+            at row ``y_halo`` of the window and the two y-halo operands
+            fill the rows above and below, over the same planes — every
+            row offset a whole sublane tile.
             """
 
             def go(make):
@@ -510,30 +539,52 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
 
             for tag in range(n_f):
                 field_ref, scr, bv = field_refs[tag], ins[tag], bvs[tag]
-                sem = in_sems.at[slot, tag]
-                if nblocks == 1:
-                    go(lambda: pltpu.make_async_copy(
-                        field_ref, scr.at[slot, pl.ds(halo, bx)], sem))
-                else:
+                # (source, window rows, semaphore) of each plane-range
+                # input; rows None is the whole plane.
+                srcs = [(field_ref, None, in_sems.at[slot, tag])]
+                if y_halo:
+                    lo_ref, hi_ref = y_halos[tag]
+                    srcs = [
+                        (field_ref, pl.ds(y_halo, ny_own),
+                         in_sems.at[slot, tag]),
+                        (lo_ref, pl.ds(0, y_halo),
+                         halo_sems.at[slot, tag, 0]),
+                        (hi_ref, pl.ds(y_halo + ny_own, y_halo),
+                         halo_sems.at[slot, tag, 1]),
+                    ]
+                for src, rows, sem in srcs:
+                    def dst(planes, rows=rows):
+                        if planes is None and rows is None:
+                            return scr.at[slot]
+                        if planes is None:
+                            planes = pl.ds(0, win_n)
+                        if rows is None:
+                            return scr.at[slot, planes]
+                        return scr.at[slot, planes, rows]
+
+                    if nblocks == 1:
+                        go(lambda: pltpu.make_async_copy(
+                            src, dst(pl.ds(halo, bx)), sem))
+                        continue
                     lo, hi = b == 0, b == nblocks - 1
 
                     @pl.when(lo)
                     def _():
                         go(lambda: pltpu.make_async_copy(
-                            field_ref.at[pl.ds(0, bx + halo)],
-                            scr.at[slot, pl.ds(halo, bx + halo)], sem))
+                            src.at[pl.ds(0, bx + halo)],
+                            dst(pl.ds(halo, bx + halo)), sem))
 
                     @pl.when(hi)
                     def _():
                         go(lambda: pltpu.make_async_copy(
-                            field_ref.at[pl.ds(b * bx - halo, bx + halo)],
-                            scr.at[slot, pl.ds(0, bx + halo)], sem))
+                            src.at[pl.ds(b * bx - halo, bx + halo)],
+                            dst(pl.ds(0, bx + halo)), sem))
 
                     @pl.when(jnp.logical_not(lo | hi))
                     def _():
                         go(lambda: pltpu.make_async_copy(
-                            field_ref.at[pl.ds(b * bx - halo, win_n)],
-                            scr.at[slot], sem))
+                            src.at[pl.ds(b * bx - halo, win_n)],
+                            dst(None), sem))
 
                 # Ghost x-planes on the slab's outer side(s): DMA'd from
                 # the face operands (``halo`` planes wide — 1 for the
@@ -657,12 +708,12 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
             writes the bx output planes."""
             k = fuse
             if x_chain:
-                # xy-chain support: when the operand is y-extended (its
+                # xy-chain support: when the plane is y-extended (its
                 # rows cover global [seeds[4], seeds[4]+ny), which may
                 # start negative or cross L), mid-stage rows outside the
                 # GLOBAL domain pin to the boundary value exactly like
                 # out-of-domain x planes — while in-domain rows of the
-                # y pad ring-recompute the y neighbor's values, the
+                # y halo ring-recompute the y neighbor's values, the
                 # property that lets the chain cross a y shard boundary.
                 # In the 1D x-chain (block spans full L in y) every row
                 # is in-domain and this mask is all-true.
@@ -697,9 +748,11 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
                         wins, const_edges, step_s, b * bx, bx
                     )
                     for i in range(n_f):
-                        out_scr[i][slot] = (
-                            centers[i] + derivs[i] * dt
-                        ).astype(dtype)
+                        res = centers[i] + derivs[i] * dt
+                        if y_halo:
+                            # Only the owned rows go back to the field.
+                            res = res[:, y_halo:y_halo + ny_own]
+                        out_scr[i][slot] = res.astype(dtype)
                 else:
                     g0 = b * bx - (k - 1 - s)
                     iota_w = lax.broadcasted_iota(
@@ -808,14 +861,18 @@ def _make_kernel(spec, nblocks, bx, nx, ny, nz, dtype, use_noise,
     static_argnames=("spec", "bx", "use_noise", "interpret", "fuse",
                      "detect_races", "mid_bf16", "variant"),
 )
-def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
-                interpret, fuse, detect_races=False, mid_bf16=False,
-                variant="chain"):
+def _fused_call(fields, params_vec, seeds, faces, y_halos, *, spec, bx,
+                use_noise, interpret, fuse, detect_races=False,
+                mid_bf16=False, variant="chain"):
     n_f = spec.n_fields
-    nx, ny, nz = fields[0].shape
+    nx, ny_own, nz = fields[0].shape
     dtype = fields[0].dtype
     nblocks = nx // bx
     with_faces = faces is not None
+    # The VMEM plane: the owned rows plus, in the xy-chain form, the
+    # y-halo rows above and below them.
+    y_halo = 0 if y_halos is None else y_halos[0].shape[1]
+    ny = ny_own + 2 * y_halo
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
@@ -831,6 +888,9 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
         if len(faces) == 6 * n_f:
             in_specs += [vmem_spec] * (4 * n_f)
         operands += list(faces)
+    if y_halos is not None:
+        in_specs += [any_spec] * (2 * n_f)
+        operands += list(y_halos)
 
     # Single-slab runs (nblocks == 1) only ever use buffer slot 0;
     # allocating the second slot would double the scratch for nothing
@@ -848,7 +908,7 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
             for _ in range(n_f)
         ]
     scratch_shapes += [
-        pltpu.VMEM((nio, bx, ny, nz), dtype) for _ in range(n_f)
+        pltpu.VMEM((nio, bx, ny_own, nz), dtype) for _ in range(n_f)
     ]
     scratch_shapes += [
         pltpu.SemaphoreType.DMA((nio, n_f)),
@@ -856,21 +916,25 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
     ]
     if with_faces:
         scratch_shapes.append(pltpu.SemaphoreType.DMA((nio, n_f, 2)))
+    if y_halo:
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((nio, n_f, 2)))
 
     out = pl.pallas_call(
         _make_kernel(
             spec, nblocks, bx, nx, ny, nz, dtype, use_noise, with_faces,
-            fuse, mid_bf16,
+            fuse, mid_bf16, y_halo,
         ),
         in_specs=in_specs,
         out_specs=[any_spec] * n_f,
         out_shape=[
-            jax.ShapeDtypeStruct((nx, ny, nz), dtype) for _ in range(n_f)
+            jax.ShapeDtypeStruct((nx, ny_own, nz), dtype)
+            for _ in range(n_f)
         ],
         scratch_shapes=scratch_shapes,
         # Each field is updated in place: a launch's input is dead after
         # it, so the result takes the operand's buffer and a loop carry
-        # needs no copy back (the x faces are separate, never aliased).
+        # needs no copy back (the x faces and y halos are separate,
+        # never aliased).
         input_output_aliases={2 + i: i for i in range(n_f)},
         # Mosaic's default scoped-VMEM cap is well below the slab budget;
         # without an explicit limit L=256 f32 OOMs at kernel-stack
@@ -894,7 +958,7 @@ def _fused_call(fields, params_vec, seeds, faces, *, spec, bx, use_noise,
 
 def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
                allow_interpret=True, fuse=1, detect_races=False,
-               offsets=None, row=None, variant=None):
+               offsets=None, row=None, variant=None, y_halos=None):
     """``fuse`` fused steps of ``spec``'s model on interior-shaped
     fields (an n-tuple of (nx, ny, nz) arrays in declaration order).
 
@@ -921,15 +985,20 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
       mid-stage ring pinning uses GLOBAL x *and y* coordinates so
       interior shards recompute the neighbor ring bitwise instead of
       freezing it).
-      The **xy-chain** is the same mode with a y-extended operand
-      (``parallel/temporal.xy_chain``): rows cover global
-      ``[offsets[1], offsets[1] + ny)`` including a fuse-deep exchanged
-      y halo (plus sublane-alignment filler rows at the high end), so
-      the chain also crosses y shard boundaries — in-domain pad rows
-      ring-recompute the y neighbor's values, out-of-domain rows pin to
-      the boundary constant, and the caller slices the y interior from
-      the result. y is the sublane dim (8/16-granularity tiling), which
-      is what makes this extension Mosaic-cheap, unlike the 128-lane z.
+      The **xy-chain** is the same mode with ``y_halos``
+      (``parallel/temporal.xy_chain``): a 2n-tuple ``(f0_ylo, f0_yhi,
+      f1_ylo, ...)`` of (nx, hy, nz) blocks, ``hy`` a whole number of
+      sublane tiles and at least ``fuse``, holding the rows below and
+      above the owned ones — the fuse-deep exchanged y halo next to the
+      owned rows, boundary-constant filler beyond it. The kernel
+      assembles each (ny + 2*hy)-row plane in VMEM (the x faces are then
+      (fuse, ny + 2*hy, nz), over the same rows), so the chain also
+      crosses y shard boundaries — in-domain halo rows ring-recompute
+      the y neighbor's values, out-of-domain rows pin to the boundary
+      constant — and it writes only the owned rows back, in place: the
+      fields need no y pad before the launch and no slice after it. y
+      is the sublane dim (8/16-granularity tiling), which is what makes
+      this extension Mosaic-cheap, unlike the 128-lane z.
 
     ``variant`` names the launch in the kernel's stamp (``gs_kernel``
     in its ``kernel_metadata``, ``gs_<variant>`` its name): by default
@@ -991,11 +1060,26 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
         offsets = jnp.zeros((3,), jnp.int32)
     offsets = jnp.asarray(offsets, jnp.int32)
     row = jnp.asarray(nz if row is None else row, jnp.int32)
+    hy = 0
+    if y_halos is not None:
+        y_halos = tuple(y_halos)
+        hy = y_halos[0].shape[1]
+        if not x_chain or len(y_halos) != 2 * n_f or hy < fuse or any(
+            h.shape != (nx, hy, nz) for h in y_halos
+        ):
+            raise ValueError(
+                f"y_halos need the x-chain faces and {2 * n_f} blocks of "
+                f"one shape ({nx}, hy >= {fuse}, {nz}); got "
+                f"{[h.shape for h in y_halos]}"
+            )
+    # The plane the kernel computes on, and its global origin.
+    ny_plane = ny + 2 * hy
+    offs_plane = offsets.at[1].add(-hy) if hy else offsets
     if x_chain:
         for f in faces:
-            if f.shape != (fuse, ny, nz):
+            if f.shape != (fuse, ny_plane, nz):
                 raise ValueError(
-                    f"x-chain faces must be ({fuse}, {ny}, {nz}); "
+                    f"x-chain faces must be ({fuse}, {ny_plane}, {nz}); "
                     f"got {f.shape}"
                 )
 
@@ -1008,7 +1092,7 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
         env_raw("GS_MID_BF16") == "1" and dtype == jnp.float32
     )
     mid_item = jnp.dtype(_mid_store_dtype(dtype, mid_bf16)).itemsize
-    bx = pick_block_planes(nx, ny, nz, dtype.itemsize, fuse,
+    bx = pick_block_planes(nx, ny_plane, nz, dtype.itemsize, fuse,
                            mid_itemsize=mid_item, n_fields=n_f)
     if bx == 0 and fuse > 1 and not x_chain:
         # The requested depth overflows VMEM for this shape, but a
@@ -1037,14 +1121,16 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
     # a whole number of tiles (measured on v5e: L=64 f32 fails "Slice
     # shape along dimension 2 must be aligned to tiling (128)"; L=128
     # compiles). Unaligned shapes take the XLA kernel, which handles any L.
+    # The y halos land above and below the owned rows, so they too are
+    # whole sublane tiles.
     sublane = 16 if dtype == jnp.bfloat16 else 8
-    aligned = nz % 128 == 0 and ny % sublane == 0
+    aligned = nz % 128 == 0 and ny % sublane == 0 and hy % sublane == 0
     if on_tpu and not aligned:
         _warn_once(
             f"Pallas kernel requested but the local grid "
-            f"({nx}x{ny}x{nz}, {dtype}) is not Mosaic-tile-aligned "
-            f"(needs nz % 128 == 0 and ny % {sublane} == 0); "
-            "running the XLA kernel instead"
+            f"({nx}x{ny}x{nz}, y halo {hy}, {dtype}) is not "
+            f"Mosaic-tile-aligned (needs nz % 128 == 0 and ny and the "
+            f"y halo % {sublane} == 0); running the XLA kernel instead"
         )
     if (dtype == jnp.float64 and on_tpu) or bx == 0 or (
         on_tpu and not aligned
@@ -1061,14 +1147,27 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
                 # exchange width matches a depth Mosaic can serve.
                 _warn_once(
                     f"x-chain fuse={fuse} does not fit VMEM for local "
-                    f"grid {nx}x{ny}x{nz} ({dtype}); running the XLA "
-                    "x-chain fallback — cap the depth with "
-                    "max_feasible_fuse"
+                    f"grid {nx}x{ny_plane}x{nz} ({dtype}); running the "
+                    "XLA x-chain fallback — cap the depth with "
+                    "max_feasible_fuse or max_feasible_fuse_ypad"
                 )
-            return _xla_xchain_fallback(
-                fields, params, seeds, faces, spec=spec, fuse=fuse,
-                use_noise=use_noise, offsets=offsets, row=row,
+            if not hy:
+                return _xla_xchain_fallback(
+                    fields, params, seeds, faces, spec=spec, fuse=fuse,
+                    use_noise=use_noise, offsets=offsets, row=row,
+                )
+            # The XLA program runs on the plane the kernel assembles in
+            # VMEM, built here in HBM, and keeps the owned rows.
+            planes = tuple(
+                jnp.concatenate([y_halos[2 * i], f, y_halos[2 * i + 1]],
+                                axis=1)
+                for i, f in enumerate(fields)
             )
+            out = _xla_xchain_fallback(
+                planes, params, seeds, faces, spec=spec, fuse=fuse,
+                use_noise=use_noise, offsets=offs_plane, row=row,
+            )
+            return tuple(o[:, hy:hy + ny] for o in out)
         for s in range(fuse):
             fields = _xla_fallback(
                 fields, params, seeds.at[2].add(s) if s else seeds,
@@ -1083,10 +1182,10 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
     params_vec = jnp.stack(
         [getattr(params, f_) for f_ in spec.param_fields]
     ).astype(smem_dtype)
-    seeds7 = jnp.concatenate([seeds, offsets, row[None]])
+    seeds7 = jnp.concatenate([seeds, offs_plane, row[None]])
     return _fused_call(
         fields, params_vec, seeds7,
-        tuple(faces) if faces is not None else None,
+        tuple(faces) if faces is not None else None, y_halos,
         spec=spec, bx=bx, use_noise=use_noise, interpret=not on_tpu,
         fuse=fuse, detect_races=detect_races and not on_tpu,
         mid_bf16=mid_bf16, variant=variant,

@@ -253,7 +253,7 @@ def _feasible_chain_depth(local, itemsize, kmax, sublane=8, ypad=True,
                           n_fields=2):
     """Deepest chain depth the real Mosaic VMEM feasibility check
     admits for this local shape (``pallas_stencil.max_feasible_fuse*``);
-    ``ypad`` selects the xy-chain form (y-extended operand) vs the 1D
+    ``ypad`` selects the xy-chain form (y-halo planes) vs the 1D
     x-chain; ``n_fields`` scales the per-slab VMEM bytes (every field
     rides the same slab pipeline)."""
     from ..ops import pallas_stencil as ps
@@ -312,9 +312,10 @@ def project_chain(
     overheads are:
 
     * ``FUSE_COST_RATIO[k]`` — in-kernel depth vs the k=5 optimum;
-    * y-plane growth — the operand carries a k-deep y halo rounded up
-      to the sublane tile, so every plane computes
-      (ny + 2k + align)/ny more rows;
+    * y-plane growth — the kernel's VMEM plane carries a k-deep y halo
+      on each side, each rounded up to the sublane tile
+      (``pallas_stencil.y_halo_rows``), so every plane computes
+      (ny + 2*hy)/ny more rows;
     * x ring recompute — mid-stage windows extend (k-1-s) planes per
       side, 1 + (k-1)/nx extra volume (same as the 1D x-chain);
     * z bands (p > 1 only) — two k-wide bands per round recomputed in
@@ -348,8 +349,9 @@ def project_chain(
     k = fuse
     sk = max(1, int(halo_depth))
     s_steps = k * sk  # in-kernel steps per exchange round
-    ny_ext = ny + 2 * s_steps
-    ny_ext += (-ny_ext) % sublane
+    from ..ops import pallas_stencil as ps
+
+    ny_ext = ny + 2 * ps.y_halo_rows(s_steps, sublane)
     y_over = ny_ext / ny if (m > 1 or p > 1) else 1.0
     x_ring = 1.0 + (s_steps - 1) / nx
     compute_us = us_base * r * y_over * x_ring

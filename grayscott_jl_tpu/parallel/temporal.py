@@ -9,11 +9,13 @@ data on every sharded axis — and what that costs depends on which
 * **x** (untiled leading dim): free — the x-chain mode consumes k-wide
   exchanged x slabs directly (round 3);
 * **y** (sublane dim, 8/16-granularity): cheap — :func:`xy_chain`
-  extends the operand by a k-deep exchanged y halo (rounded up to the
-  sublane tile with boundary-constant filler rows) and the kernel's
-  mid-stage global-coordinate pinning makes in-domain pad rows
-  ring-recompute the y neighbor's values, so the in-kernel chain
-  crosses y shard boundaries at a few percent of plane-area overhead;
+  hands the kernel k-deep exchanged y halos (each rounded up to the
+  sublane tile with boundary-constant filler rows), which it places
+  above and below the owned rows in VMEM; the kernel's mid-stage
+  global-coordinate pinning makes in-domain halo rows ring-recompute
+  the y neighbor's values, so the in-kernel chain crosses y shard
+  boundaries at a few percent of plane-area overhead, and the owned
+  rows are updated in place;
 * **z** (128-lane dim): expensive — a ±k z pad would round the lane
   extent up to the next 128 multiple (up to ~50% wasted vector work),
   so z shard boundaries are instead handled OUTSIDE the kernel:
@@ -217,11 +219,16 @@ def xy_chain(
     round, the same 6 (z-sharded) or 4 collectives, amortized over k
     times the steps.
 
-    ``chain_kernel(fields_p, faces, step, offs_p)`` runs the fused
-    kernel (or its bitwise XLA fallback) at ``fuse=depth`` on the
-    y-extended operand tuple; ``unit_noise(step_idx, origin, shape)``
-    draws from the shared position-keyed stream. Must be called inside
-    ``shard_map``.
+    ``chain_kernel(fields, y_halos, faces, step, offs)`` runs the fused
+    kernel (or its bitwise XLA fallback) at ``fuse=depth`` on the owned
+    blocks, in place: ``y_halos`` are the per-field (lo, hi) blocks of
+    ``pallas_stencil.y_halo_rows(depth, sublane)`` rows each (the
+    ``depth`` exchanged rows next to the owned ones, boundary-constant
+    filler beyond), and the x faces span the same rows as the kernel's
+    VMEM plane (``fused_step``'s xy-chain form). So the round neither
+    pads the fields in y nor slices the result.
+    ``unit_noise(step_idx, origin, shape)`` draws from the shared
+    position-keyed stream. Must be called inside ``shard_map``.
 
     ``overlap=True`` is the split-phase form (docs/OVERLAP.md): the
     SAME exchange is issued first, but the kernel consumes frozen
@@ -240,12 +247,15 @@ def xy_chain(
     interior to hide behind); shallower blocks silently take the fused
     round, which is bitwise identical anyway.
     """
+    from ..ops.pallas_stencil import y_halo_rows
+
     fields = tuple(fields)
     bvs = tuple(boundaries)
     nx, ny, nz = fields[0].shape
     dtype = fields[0].dtype
     dims = axis_sizes
     k = depth
+    hy = y_halo_rows(k, sublane)
     z_sharded = dims[2] > 1
     if overlap and not xy_overlap_feasible(fields[0].shape, dims, k):
         overlap = False  # no comm-independent interior: fused round
@@ -256,18 +266,19 @@ def xy_chain(
     # the split-phase x/y band recompute, built beside the exchange.
     band_jobs = []
 
-    def const_faces(shape_nyz):
-        return tuple(
-            jnp.full((k,) + shape_nyz, bv, dtype)
-            for bv in bvs for _ in (0, 1)
-        )
-
     def interleave(los, his):
         """Field-major (lo, hi) faces tuple from per-field slabs."""
         return tuple(x for pair in zip(los, his) for x in pair)
 
+    def fill_y(a, bv, lo_rows, hi_rows):
+        """``a`` with boundary-constant filler rows below and above."""
+        if not (lo_rows or hi_rows):
+            return a
+        return jnp.pad(a, ((0, 0), (lo_rows, hi_rows), (0, 0)),
+                       constant_values=bv)
+
     if z_sharded:
-        # One corner-propagated k-deep frame serves the kernel operand,
+        # One corner-propagated k-deep frame serves the kernel's y halos,
         # its x faces, AND the band windows (6 ppermutes total).
         fields_w = halo.halo_pad_wide(
             fields, bvs, axis_names, dims, k
@@ -276,11 +287,6 @@ def xy_chain(
             # Split phase: the kernel sees frozen constants everywhere,
             # so the frame has NO consumer on the kernel's dataflow
             # path; bands for every sharded axis are stitched after.
-            fields_p = tuple(
-                jnp.pad(f, ((0, 0), (k, k), (0, 0)), constant_values=bv)
-                for f, bv in zip(fields, bvs)
-            )
-            faces = const_faces((ny + 2 * k, nz))
             m_y = ny + 2 * k
 
             def fr(x0, x1, ys):
@@ -326,101 +332,100 @@ def xy_chain(
                         slice(k, k + ny), (d_x, 0, 0),
                     ))
         else:
-            fields_p = tuple(w[k:k + nx, :, k:k + nz] for w in fields_w)
-            faces = interleave(
-                tuple(w[0:k, :, k:k + nz] for w in fields_w),
-                tuple(w[k + nx:, :, k:k + nz] for w in fields_w),
-            )
+            y_pairs = [(w[k:k + nx, :k, k:k + nz],
+                        w[k:k + nx, k + ny:, k:k + nz]) for w in fields_w]
+            x_pairs = [(w[:k, :, k:k + nz], w[k + nx:, :, k:k + nz])
+                       for w in fields_w]
     else:
         # Lean 4-ppermute build: k-wide y slabs first, then x slabs of
-        # the y-padded fields so the x faces carry y corner data.
+        # the y-padded fields so the x faces carry y corner data. The
+        # launch overwrites the fields, so all else the round reads of
+        # them — the slabs the exchange sends, the bands' rows and
+        # planes — is cut first: c rows and c planes at each end, which
+        # the barrier keeps ahead of the launch (else XLA keeps the
+        # fields alive past it with a whole-field copy).
+        c = 2 * k if overlap else k
+        ye = tuple(jnp.concatenate([f[:, :c], f[:, ny - c:]], axis=1)
+                   for f in fields)
+        xe = tuple(jnp.concatenate([f[:c], f[nx - c:]]) for f in fields)
+        ye, xe, fields = lax.optimization_barrier((ye, xe, fields))
         y_pairs = halo.exchange_slabs(
-            list(fields), bvs, 1, axis_names[1], dims[1], k
+            list(ye), bvs, 1, axis_names[1], dims[1], k
         )
-        fields_pr = tuple(
-            jnp.concatenate([lo, f, hi], axis=1)
-            for f, (lo, hi) in zip(fields, y_pairs)
+        # The x ends of the y-padded fields [y lo | field | y hi]: the
+        # first and last c planes, (2c, ny + 2k, nz).
+        xe_pr = tuple(
+            jnp.concatenate([jnp.concatenate([lo[:c], lo[nx - c:]]), e,
+                             jnp.concatenate([hi[:c], hi[nx - c:]])],
+                            axis=1)
+            for e, (lo, hi) in zip(xe, y_pairs)
         )
         x_pairs = halo.exchange_slabs(
-            list(fields_pr), bvs, 0, axis_names[0], dims[0], k
+            list(xe_pr), bvs, 0, axis_names[0], dims[0], k
         )
-        if overlap:
-            fields_p = tuple(
-                jnp.pad(f, ((0, 0), (k, k), (0, 0)), constant_values=bv)
-                for f, bv in zip(fields, bvs)
-            )
-            faces = const_faces((ny + 2 * k, nz))
+        if overlap and dims[1] > 1:
+            # y bands: body rows are [arrived y slab | 2k owned rows]
+            # of the y-padded fields; the x faces are the arrived x
+            # slabs clipped to the same rows, so the band's x corners
+            # carry real neighbor data exactly as the fused kernel's do.
             m_y = ny + 2 * k
-            if dims[1] > 1:
-                # y bands: body rows are [arrived y slab | 2k owned
-                # rows] of the y-padded fields; the x faces are the
-                # arrived x slabs clipped to the same rows, so the
-                # band's x corners carry real neighbor data exactly as
-                # the fused kernel's do.
-                for ys, o_y, d_y in (
-                    (slice(0, 3 * k), -k, 0),
-                    (slice(m_y - 3 * k, m_y), ny - 2 * k, ny - k),
-                ):
-                    band_jobs.append((
-                        tuple(f[:, ys, :] for f in fields_pr),
-                        interleave(
-                            tuple(lo[:, ys, :] for lo, _ in x_pairs),
-                            tuple(hi[:, ys, :] for _, hi in x_pairs),
-                        ),
-                        jnp.stack([offs[0], offs[1] + o_y, offs[2]]),
-                        slice(k, 2 * k), (0, d_y, 0),
-                    ))
-            if dims[0] > 1:
-                # x bands: a k-plane body whose x faces are the arrived
-                # slab and the adjacent owned planes (both y-padded).
-                for body, faces_b, o_x, d_x in (
-                    (tuple(f[:k] for f in fields_pr),
-                     interleave(
-                         tuple(lo for lo, _ in x_pairs),
-                         tuple(f[k:2 * k] for f in fields_pr),
-                     ),
-                     0, 0),
-                    (tuple(f[nx - k:] for f in fields_pr),
-                     interleave(
-                         tuple(f[nx - 2 * k:nx - k] for f in fields_pr),
-                         tuple(hi for _, hi in x_pairs),
-                     ),
-                     nx - k, nx - k),
-                ):
-                    band_jobs.append((
-                        body, faces_b,
-                        jnp.stack([offs[0] + o_x, offs[1] - k,
-                                   offs[2]]),
-                        slice(k, k + ny), (d_x, 0, 0),
-                    ))
-        else:
-            fields_p = fields_pr
-            faces = interleave(
-                tuple(lo for lo, _ in x_pairs),
-                tuple(hi for _, hi in x_pairs),
-            )
+            for body, ys, o_y, d_y in (
+                (tuple(jnp.concatenate([lo, e[:, :2 * k]], axis=1)
+                       for e, (lo, _) in zip(ye, y_pairs)),
+                 slice(0, 3 * k), -k, 0),
+                (tuple(jnp.concatenate([e[:, 2 * k:], hi], axis=1)
+                       for e, (_, hi) in zip(ye, y_pairs)),
+                 slice(m_y - 3 * k, m_y), ny - 2 * k, ny - k),
+            ):
+                band_jobs.append((
+                    body,
+                    interleave(
+                        tuple(lo[:, ys, :] for lo, _ in x_pairs),
+                        tuple(hi[:, ys, :] for _, hi in x_pairs),
+                    ),
+                    jnp.stack([offs[0], offs[1] + o_y, offs[2]]),
+                    slice(k, 2 * k), (0, d_y, 0),
+                ))
+        if overlap and dims[0] > 1:
+            # x bands: a k-plane body whose x faces are the arrived
+            # slab and the adjacent owned planes (both y-padded).
+            for body, faces_b, d_x in (
+                (tuple(e[:k] for e in xe_pr),
+                 interleave(tuple(lo for lo, _ in x_pairs),
+                            tuple(e[k:2 * k] for e in xe_pr)),
+                 0),
+                (tuple(e[3 * k:] for e in xe_pr),
+                 interleave(tuple(e[2 * k:3 * k] for e in xe_pr),
+                            tuple(hi for _, hi in x_pairs)),
+                 nx - k),
+            ):
+                band_jobs.append((
+                    body, faces_b,
+                    jnp.stack([offs[0] + d_x, offs[1] - k, offs[2]]),
+                    slice(k, k + ny), (d_x, 0, 0),
+                ))
 
-    # Round the y extent up to the sublane tile with boundary-constant
-    # filler rows at the high end — Mosaic needs sublane-aligned planes,
-    # and extra rows only push the contamination front farther from the
-    # interior (they are sliced away with the rest of the pad).
-    extra = (-(ny + 2 * k)) % sublane
-    if extra:
-        def pad_y(a, bv):
-            return jnp.pad(
-                a, ((0, 0), (0, extra), (0, 0)), constant_values=bv
-            )
-
-        fields_p = tuple(
-            pad_y(f, bv) for f, bv in zip(fields_p, bvs)
+    if overlap:
+        y_halos = tuple(
+            jnp.full((nx, hy, nz), bv, dtype) for bv in bvs for _ in (0, 1)
         )
         faces = tuple(
-            pad_y(fc, bvs[i // 2]) for i, fc in enumerate(faces)
+            jnp.full((k, ny + 2 * hy, nz), bv, dtype)
+            for bv in bvs for _ in (0, 1)
         )
-
-    offs_p = jnp.stack([offs[0], offs[1] - k, offs[2]])
-    out = chain_kernel(fields_p, faces, step, offs_p)
-    out = tuple(f[:, k:k + ny, :] for f in out)
+    else:
+        # The exchanged rows next to the owned ones, boundary-constant
+        # filler beyond them: every halo is hy rows, whole sublane
+        # tiles, and the x faces span the kernel's whole plane.
+        y_halos = tuple(
+            h for (lo, hi), bv in zip(y_pairs, bvs)
+            for h in (fill_y(lo, bv, hy - k, 0), fill_y(hi, bv, 0, hy - k))
+        )
+        faces = tuple(
+            fill_y(f, bv, hy - k, hy - k)
+            for pair, bv in zip(x_pairs, bvs) for f in pair
+        )
+    out = chain_kernel(fields, y_halos, faces, step, offs)
 
     # Split-phase x/y bands first (they reproduce the fused kernel's
     # values, including each other's corners), then the z bands, which

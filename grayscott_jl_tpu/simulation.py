@@ -1354,13 +1354,14 @@ class Simulation:
                             kernel_step(fields_c, step, faces_full)
                         )
 
-                    def chain_kernel(fields_p, faces_p, stp, offs_p):
+                    def chain_kernel(fields_k, y_halos, faces_k, stp,
+                                     offs_k):
                         return pallas_stencil.fused_step(
-                            fields_p, params, step_seeds(stp), faces_p,
+                            fields_k, params, step_seeds(stp), faces_k,
                             spec=spec, use_noise=use_noise,
                             allow_interpret=allow_interpret,
-                            fuse=depth, offsets=offs_p, row=L,
-                            variant="xy_chain",
+                            fuse=depth, offsets=offs_k, row=L,
+                            variant="xy_chain", y_halos=y_halos,
                         )
 
                     def band_kernel(fields_b, faces_b, stp, offs_b):

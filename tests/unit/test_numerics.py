@@ -266,6 +266,62 @@ def test_field_copies_census():
     )
 
 
+_RELAYOUT_HLO = """\
+HloModule jit_gs_step, input_output_alias={ {0}: (0, {}, may-alias) }
+
+%fused_computation.7 (param_0.1: f32[64,128,128]) -> f32[64,144,128] {
+  %param_0.1 = f32[64,128,128]{2,1,0} parameter(0)
+  %constant.2 = f32[] constant(0)
+  %concatenate.9 = f32[64,256,128]{2,1,0} concatenate(%param_0.1, %param_0.1), dimensions={1}
+  ROOT %pad.4 = f32[64,144,128]{2,1,0:T(8,128)} pad(%param_0.1, %constant.2), padding=0_0x8_8x0_0
+}
+
+%fused_computation.8 (param_0.2: f32[64,128,128], param_1.2: f32[2,128,128]) -> f32[64,128,128] {
+  %param_0.2 = f32[64,128,128]{2,1,0} parameter(0)
+  %param_1.2 = f32[2,128,128]{2,1,0} parameter(1)
+  %constant.3 = s32[] constant(0)
+  ROOT %dynamic-update-slice.1 = f32[64,128,128]{2,1,0} dynamic-update-slice(%param_0.2, %param_1.2, %constant.3, %constant.3, %constant.3)
+}
+
+%body (p: (s32[], f32[64,128,128], f32[64,128,128])) -> (s32[], f32[64,128,128], f32[64,128,128]) {
+  %pad.1187 = f32[64,136,128]{2,1,0:T(8,128)} pad(f32[64,128,128]{2,1,0:T(8,128)} %gte.1, f32[] %c), padding=0_0x4_4x0_0
+  %slice.3071 = f32[64,128,128]{2,1,0:T(8,128)} slice(%out.1), slice={[0:64], [4:132], [0:128]}
+  %fusion.5 = f32[64,144,128]{2,1,0:T(8,128)} fusion(%gte.2), kind=kLoop, calls=%fused_computation.7
+  %slice.12 = f32[2,136,128]{2,1,0:T(8,128)} slice(%pad.1187), slice={[0:2], [0:136], [0:128]}
+  %concatenate.3 = f32[4,128,128]{2,1,0} concatenate(%a, %b), dimensions={0}
+  %fusion.6 = f32[64,128,128]{2,1,0:T(8,128)} fusion(%gte.1, %face), kind=kLoop, calls=%fused_computation.8
+  %slice.13 = bf16[64,128,128]{2,1,0} slice(%cast), slice={[0:64], [0:128], [0:128]}
+}
+
+ENTRY %main.6 (fields_0_.1: f32[64,128,128], fields_1_.1: f32[64,128,128], params_dt.1: f32[]) -> (f32[64,128,128], f32[64,128,128]) {
+  ROOT %tuple.1 = (f32[64,128,128]{2,1,0}, f32[64,128,128]{2,1,0}) tuple(%x, %y)
+}
+"""
+
+
+def test_field_relayouts_census():
+    """Field-size ``pad``, ``slice`` and ``concatenate`` results, alone
+    or as a fusion's root: here the y pad, the slice of the owned rows,
+    the fusion rooted in a pad, and a bf16 slice of field size — four.
+    The face-sized slice and concatenate, the in-place
+    dynamic-update-slice fusion, the ops inside a fusion other than its
+    root, and the tuple ROOT are not counted."""
+    assert obs_xstats.field_relayouts(_RELAYOUT_HLO, 2) == 4
+    assert obs_xstats.field_relayouts(_COPY_HLO, 2) == 0
+    assert obs_xstats.field_relayouts("add mul", 2) == 0
+
+    class Compiled:
+        def as_text(self):
+            return _RELAYOUT_HLO
+
+    rec = obs_xstats.capture(Compiled(), name="r", compile_s=0.1,
+                             n_fields=2)
+    assert rec["field_relayouts"] == 4
+    assert "field_relayouts" not in obs_xstats.capture(
+        Compiled(), name="r", compile_s=0.1
+    )
+
+
 def test_capture_degrades_on_alien_compiled_object():
     class Alien:
         def cost_analysis(self):

@@ -582,6 +582,61 @@ def test_xy_chain_edge_block_pins_out_of_domain_rows(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("fuse", [2, 3])
+@pytest.mark.parametrize("y0", [0, 16], ids=["y-edge", "y-interior"])
+def test_xy_chain_halo_operands_match_padded_operand(fuse, y0, monkeypatch):
+    """The xy-chain form — owned fields in place plus a sublane tile of
+    y halo per side, assembled into the plane in VMEM — gives owned
+    rows BITWISE equal to the same kernel in x-chain mode on the
+    y-extended operand built here with pad and concatenate (the form
+    the 1D x-chain keeps). Three slabs (GS_BX=16) cover the lo,
+    interior and hi DMA branches; a global-y-edge shard has
+    out-of-domain lo halo rows (the exchange's boundary constant), an
+    interior shard real neighbour rows on both sides."""
+    nx, ny, nz, hy = 48, 16, 128, 8
+    u, v, _, params, seeds = _xchain_inputs(nx, ny, nz, fuse)
+    rng = np.random.default_rng(17)
+    bvs = (gs_model.U_BOUNDARY, gs_model.V_BOUNDARY)
+
+    def rows(bv, edge=False):
+        if edge:
+            return jnp.full((nx, fuse, nz), bv, jnp.float32)
+        return jnp.asarray(rng.random((nx, fuse, nz)), jnp.float32)
+
+    def fill(a, bv, lo, hi):
+        return jnp.pad(a, ((0, 0), (lo, hi), (0, 0)), constant_values=bv)
+
+    # The exchanged rows next to the owned ones, filler beyond them.
+    y_halos = tuple(
+        h for bv in bvs
+        for h in (fill(rows(bv, edge=y0 == 0), bv, hy - fuse, 0),
+                  fill(rows(bv), bv, 0, hy - fuse))
+    )
+    faces = tuple(
+        jnp.asarray(rng.random((fuse, ny + 2 * hy, nz)), jnp.float32)
+        for _ in range(4)
+    )
+    offs = jnp.asarray([16, y0, 0], jnp.int32)
+    row = jnp.int32(64)
+    monkeypatch.setenv("GS_BX", "16")
+    got = fused_step(
+        u, v, params, seeds, faces, use_noise=True, fuse=fuse,
+        offsets=offs, row=row, y_halos=y_halos, variant="xy_chain",
+    )
+    padded = tuple(
+        jnp.concatenate([y_halos[2 * i], f, y_halos[2 * i + 1]], axis=1)
+        for i, f in enumerate((u, v))
+    )
+    want = fused_step(
+        *padded, params, seeds, faces, use_noise=True, fuse=fuse,
+        offsets=offs.at[1].add(-hy), row=row,
+    )
+    for g, w in zip(got, want):
+        assert g.shape == (nx, ny, nz)
+        np.testing.assert_array_equal(np.asarray(g),
+                                      np.asarray(w[:, hy:hy + ny]))
+
+
 def test_x_chain_rejects_bad_faces():
     u, v, faces, params, seeds = _xchain_inputs(k=3)
     with pytest.raises(ValueError, match="fuse >= 2"):

@@ -64,10 +64,12 @@ def test_pipelined_kernel_has_no_dma_races():
 
 
 def _chain_race_case(nx, ny, nz, k, offs, row, seed, monkeypatch,
-                     bx=None):
+                     bx=None, y_halo=0):
     """Shared scaffolding for the chain-mode race tests: random fields
     and faces, fused_step under the race detector vs the XLA chain
-    fallback, both fields asserted."""
+    fallback, both fields asserted. ``y_halo`` rows of random y halo per
+    side give the xy-chain form, whose x faces span the owned rows and
+    both halos; the fallback then runs on the assembled plane."""
     import jax
     import jax.numpy as jnp
 
@@ -84,10 +86,17 @@ def _chain_race_case(nx, ny, nz, k, offs, row, seed, monkeypatch,
     u = jax.random.uniform(key, (nx, ny, nz), dtype)
     v = jax.random.uniform(jax.random.fold_in(key, 1), (nx, ny, nz), dtype)
     faces = tuple(
-        jax.random.uniform(jax.random.fold_in(key, 2 + i), (k, ny, nz),
-                           dtype)
+        jax.random.uniform(jax.random.fold_in(key, 2 + i),
+                           (k, ny + 2 * y_halo, nz), dtype)
         for i in range(4)
     )
+    y_halos = None
+    if y_halo:
+        y_halos = tuple(
+            jax.random.uniform(jax.random.fold_in(key, 6 + i),
+                               (nx, y_halo, nz), dtype)
+            for i in range(4)
+        )
     seeds = jnp.asarray([9, 8, 7], jnp.int32)
     offs = jnp.asarray(offs, jnp.int32)
     row = jnp.int32(row)
@@ -97,13 +106,23 @@ def _chain_race_case(nx, ny, nz, k, offs, row, seed, monkeypatch,
         monkeypatch.setenv("GS_BX", str(bx))
     u1, v1 = pallas_stencil.fused_step(
         (u, v), params, seeds, faces, spec=spec, use_noise=True, fuse=k,
-        offsets=offs, row=row, detect_races=True,
+        offsets=offs, row=row, detect_races=True, y_halos=y_halos,
     )
     assert not _races_found()
     monkeypatch.undo()
-    want_u, want_v = pallas_stencil._xla_xchain_fallback(
-        (u, v), params, seeds, faces, spec=spec, fuse=k, use_noise=True,
-        offsets=offs, row=row,
+    planes = (u, v)
+    if y_halo:
+        planes = tuple(
+            jnp.concatenate([y_halos[2 * i], f, y_halos[2 * i + 1]], 1)
+            for i, f in enumerate(planes)
+        )
+        offs = offs.at[1].add(-y_halo)
+    want_u, want_v = (
+        f[:, y_halo:y_halo + ny]
+        for f in pallas_stencil._xla_xchain_fallback(
+            planes, params, seeds, faces, spec=spec, fuse=k,
+            use_noise=True, offsets=offs, row=row,
+        )
     )
     np.testing.assert_allclose(
         np.asarray(u1), np.asarray(want_u), rtol=1e-4, atol=2e-6
@@ -123,14 +142,16 @@ def test_x_chain_kernel_has_no_dma_races(monkeypatch):
 
 
 def test_xy_chain_kernel_has_no_dma_races(monkeypatch):
-    """The xy-chain variant: y-EXTENDED operand (interior + 2k halo +
-    sublane filler) on a GLOBAL-y-EDGE shard (offsets[1] = -k, so the
-    out-of-domain y-pin branch executes) with fuse-wide x faces of the
-    same widened planes — the widened-plane slab and face DMAs must
-    stay race-free and match the XLA xy-chain fallback."""
+    """The xy-chain variant: owned rows DMA'd into the middle of each
+    widened VMEM plane, a sublane tile of y halo above and below it
+    from the halo operands, and fuse-wide x faces of the widened
+    planes, all while the owned rows are written back in place — on a
+    GLOBAL-y-EDGE shard (offsets[1] = 0, so the out-of-domain y-pin
+    branch executes). The DMAs must stay race-free and match the XLA
+    xy-chain fallback."""
     k = 3
-    _chain_race_case(32, 8 + 2 * k + 2, 128, k, offs=[32, -k, 0],
-                     row=64, seed=13, monkeypatch=monkeypatch, bx=16)
+    _chain_race_case(32, 8, 128, k, offs=[32, 0, 0], row=64, seed=13,
+                     monkeypatch=monkeypatch, bx=16, y_halo=8)
 
 
 def test_single_buffer_whole_block_slab_has_no_dma_races():

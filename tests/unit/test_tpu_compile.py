@@ -64,9 +64,11 @@ def mosaic(monkeypatch):
 
 
 def _compile(sharding, model_name, dtype, *, fuse=1, field_shape=None,
-             face_depth=None, variant=None):
+             face_depth=None, variant=None, y_halo=None):
     """Compile ``fuse`` fused steps of the model's generated kernel for
-    one described chip; returns the compiled program's HLO text."""
+    one described chip; returns the compiled program's HLO text.
+    ``y_halo`` rows of y halo per side give the xy-chain form, whose x
+    faces span the owned rows and both halos."""
     model = get_model(model_name)
     spec = kernelgen.get_spec(model)
     settings = Settings(L=L, noise=0.1, precision="Float32",
@@ -80,19 +82,25 @@ def _compile(sharding, model_name, dtype, *, fuse=1, field_shape=None,
     fields = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
                    for _ in range(spec.n_fields))
     seeds = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=sharding)
-    faces = None
+    faces = y_halos = None
+    plane = shape[1] + 2 * (y_halo or 0)
     if face_depth is not None:
-        face = jax.ShapeDtypeStruct((face_depth,) + shape[1:], dtype,
+        face = jax.ShapeDtypeStruct((face_depth, plane, shape[2]), dtype,
                                     sharding=sharding)
         faces = (face,) * (2 * spec.n_fields)
+    if y_halo is not None:
+        halo = jax.ShapeDtypeStruct((shape[0], y_halo, shape[2]), dtype,
+                                    sharding=sharding)
+        y_halos = (halo,) * (2 * spec.n_fields)
 
-    def step(fields, params, seeds, faces):
+    def step(fields, params, seeds, faces, y_halos):
         return pallas_stencil.fused_step(
             fields, params, seeds, faces, spec=spec, use_noise=True,
-            fuse=fuse, variant=variant,
+            fuse=fuse, variant=variant, y_halos=y_halos,
         )
 
-    compiled = jax.jit(step).lower(fields, params, seeds, faces).compile()
+    compiled = jax.jit(step).lower(fields, params, seeds, faces,
+                                   y_halos).compile()
     return compiled.as_text()
 
 
@@ -112,15 +120,16 @@ def test_single_block_kernel_compiles(one_chip, mosaic, model_name, dtype,
 
 def test_xy_chain_kernel_compiles(one_chip, mosaic):
     """The sharded chain kernel at the 2x2x1 local block of L=128: the
-    y-extended operand (fuse-deep halo rows plus sublane filler, as
-    ``parallel/temporal.xy_chain`` builds it) with fuse-wide x faces."""
+    owned fields in place, a sublane tile of y halo above and below
+    (as ``parallel/temporal.xy_chain`` builds them), and fuse-wide x
+    faces as tall as the kernel's VMEM plane."""
     fuse = 2
     nx, ny, nz = L // 2, L // 2, L
-    ny_p = ny + 2 * fuse
-    ny_p += -ny_p % 8
     hlo = _compile(one_chip, "grayscott", jnp.float32, fuse=fuse,
-                   field_shape=(nx, ny_p, nz), face_depth=fuse)
+                   field_shape=(nx, ny, nz), face_depth=fuse,
+                   y_halo=pallas_stencil.y_halo_rows(fuse))
     assert 'custom_call_target="tpu_custom_call"' in hlo
+    assert "output_to_operand_aliasing" in hlo
 
 
 def _kernel_metadata(hlo):
@@ -132,36 +141,115 @@ def _kernel_metadata(hlo):
     return json.loads(m.group(1))
 
 
-@pytest.mark.parametrize("variant,face_depth,want", [
-    pytest.param(None, None, "chain", id="one-chip-chain"),
-    pytest.param(None, 2, "x_chain", id="x-chain"),
-    pytest.param("xy_chain", 2, "xy_chain", id="xy-chain"),
+@pytest.mark.parametrize("variant,face_depth,y_halo,want", [
+    pytest.param(None, None, None, "chain", id="one-chip-chain"),
+    pytest.param(None, 2, None, "x_chain", id="x-chain"),
+    pytest.param("xy_chain", 2, 8, "xy_chain", id="xy-chain"),
 ])
 def test_kernel_launch_is_stamped(one_chip, mosaic, variant, face_depth,
-                                  want):
+                                  y_halo, want):
     """Each launch is named ``gs_<variant>`` and carries its variant and
     chain depth in ``kernel_metadata``: what a profiler trace's op text
     shows and the kernel readers of the benchmark key on."""
     fuse = 2
     shape = (L // 2, L // 2 + 8, L) if face_depth else None
+    if y_halo:
+        shape = (L // 2, L // 2, L)
     hlo = _compile(one_chip, "grayscott", jnp.float32, fuse=fuse,
                    field_shape=shape, face_depth=face_depth,
-                   variant=variant)
+                   variant=variant, y_halo=y_halo)
     assert _kernel_metadata(hlo) == {"gs_kernel": want, "fuse": str(fuse)}
     assert f"%gs_{want}" in hlo
 
 
-@pytest.mark.parametrize("face_depth", [
+def _xy_chain_rounds_hlo(topo, L=L):
+    """HLO of three split-phase xy-chain rounds
+    (``parallel/temporal.xy_chain`` with the overlap band cuts) in a
+    ``lax.fori_loop`` over donated fields, sharded 2x2x1 at side ``L``."""
+    import numpy as np
+    from jax import lax, shard_map
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from grayscott_jl_tpu.parallel import temporal
+
+    model = get_model("grayscott")
+    spec = kernelgen.get_spec(model)
+    settings = Settings(L=L, noise=0.1, precision="Float32",
+                        backend="TPU", kernel_language="Pallas")
+    names, dims, k = ("x", "y", "z"), (2, 2, 1), 2
+    mesh = Mesh(np.array(topo.devices).reshape(dims), names)
+    block, rep = P(*names), P()
+
+    def described(shape, dtype, pspec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, pspec))
+
+    fields = (described((L, L, L), jnp.float32, block),) * 2
+    params = jax.tree.map(lambda p: described(p.shape, p.dtype, rep),
+                          model.make_params(settings, jnp.float32))
+    seeds = described((3,), jnp.int32, rep)
+
+    def local(u, v, params, seeds):
+        offs = jnp.stack([lax.axis_index(n) * (L // d)
+                          for n, d in zip(names, dims)]).astype(jnp.int32)
+
+        def chain_kernel(fields_k, y_halos, faces_k, stp, offs_k):
+            return pallas_stencil.fused_step(
+                fields_k, params, seeds.at[2].set(stp), faces_k,
+                spec=spec, fuse=k, offsets=offs_k, row=L,
+                variant="xy_chain", y_halos=y_halos,
+            )
+
+        def band_kernel(fields_b, faces_b, stp, offs_b):
+            return pallas_stencil._xla_xchain_fallback(
+                fields_b, params, seeds.at[2].set(stp), faces_b,
+                spec=spec, fuse=k, use_noise=True, offsets=offs_b, row=L,
+            )
+
+        def body(i, carry):
+            return temporal.xy_chain(
+                carry, params, model, depth=k, step=seeds[2] + k * i,
+                offs=offs, chain_kernel=chain_kernel, use_noise=True,
+                unit_noise=None, row=L, axis_names=names, axis_sizes=dims,
+                boundaries=spec.boundaries, overlap=True,
+                band_kernel=band_kernel,
+            )
+
+        return lax.fori_loop(0, 3, body, (u, v))
+
+    run = shard_map(local, mesh=mesh, in_specs=(block, block, rep, rep),
+                    out_specs=(block, block), check_vma=False)
+    return jax.jit(run, donate_argnums=(0, 1)).lower(
+        *fields, params, seeds).compile().as_text()
+
+
+@pytest.mark.parametrize("form", [
     pytest.param(None, id="one-chip-chain"),
-    pytest.param(2, id="x-chain"),
+    pytest.param("x", id="x-chain"),
+    pytest.param("xy", id="xy-chain"),
 ])
-def test_chain_loop_updates_fields_in_place(one_chip, mosaic, face_depth):
+def test_chain_loop_updates_fields_in_place(topo, one_chip, mosaic, form):
     """Three fuse-2 launches in a ``lax.fori_loop`` over donated fields,
     as the step runner drives them: the launch aliases each field to
-    its output, so the loop carry needs no whole-field copy."""
+    its output, so the loop carry needs no whole-field copy. The
+    xy-chain case runs three whole split-phase rounds on a 2x2x1 mesh,
+    whose launch takes the owned blocks and their y halos, so no field
+    is padded or sliced either. It runs at L=256: at L=128 the 4 MB
+    blocks fit in VMEM, and the compiler keeps them there, with a
+    prefetch and an eviction of the carry that a full-size block
+    never has."""
     from jax import lax
 
     from grayscott_jl_tpu.obs import xstats
+
+    if form == "xy":
+        hlo = _xy_chain_rounds_hlo(topo, 2 * L)
+        assert 'custom_call_target="tpu_custom_call"' in hlo
+        assert "output_to_operand_aliasing" in hlo
+        assert xstats.field_copies(hlo, 2) == 0
+        assert xstats.field_relayouts(hlo, 2) == 0
+        return
 
     model = get_model("grayscott")
     spec = kernelgen.get_spec(model)
@@ -171,14 +259,14 @@ def test_chain_loop_updates_fields_in_place(one_chip, mosaic, face_depth):
     def described(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    shape = (L // 2, L, L) if face_depth else (L, L, L)
+    shape = (L // 2, L, L) if form else (L, L, L)
     fields = (described(shape), described(shape))
     params = jax.tree.map(lambda p: described(p.shape, p.dtype),
                           model.make_params(settings, jnp.float32))
     seeds = described((3,), jnp.int32)
     faces = None
-    if face_depth:
-        faces = (described((face_depth,) + shape[1:]),) * 4
+    if form:
+        faces = (described((2,) + shape[1:]),) * 4
 
     def run(fields, params, seeds, faces):
         def body(i, carry):
@@ -193,3 +281,4 @@ def test_chain_loop_updates_fields_in_place(one_chip, mosaic, face_depth):
         fields, params, seeds, faces).compile().as_text()
     assert "output_to_operand_aliasing" in hlo
     assert xstats.field_copies(hlo, 2) == 0
+    assert xstats.field_relayouts(hlo, 2) == 0

@@ -377,12 +377,13 @@ def test_x_chain_kernel_on_hardware():
 
 @requires_tpu
 def test_xy_chain_kernel_on_hardware():
-    """The Mosaic-compiled xy-chain (round 4): a y-EXTENDED operand —
-    interior + 2k-deep y halo + sublane filler rows, global y origin
-    negative — through the in-kernel chain with global-(x,y) mid-stage
-    ring pinning, against the XLA xy-chain fallback. This is the kernel
-    the (n, m, 1) pod meshes launch; catches Mosaic lowering faults in
-    the widened-plane slab walk that interpret mode cannot."""
+    """The Mosaic-compiled xy-chain (round 4): the owned rows in place
+    plus a sublane tile of y halo per side, assembled in VMEM — global
+    y origin of the plane negative — through the in-kernel chain with
+    global-(x,y) mid-stage ring pinning, against the XLA xy-chain
+    fallback on the same plane. This is the kernel the (n, m, 1) pod
+    meshes launch; catches Mosaic lowering faults in the widened-plane
+    slab walk and the halo DMAs that interpret mode cannot."""
     import jax.numpy as jnp
 
     from grayscott_jl_tpu.config.settings import Settings
@@ -390,8 +391,8 @@ def test_xy_chain_kernel_on_hardware():
     from grayscott_jl_tpu.ops import pallas_stencil
 
     nx, nz, k = 128, 256, 4
-    ny_int = 128
-    ny = ny_int + 2 * k  # 136 = 17 sublanes, already 8-aligned
+    ny = 128
+    hy = pallas_stencil.y_halo_rows(k)
     s = Settings(L=512, noise=0.2, precision="Float32", backend="TPU",
                  kernel_language="Pallas", Du=0.2, Dv=0.1, F=0.02,
                  k=0.048, dt=1.0)
@@ -401,32 +402,40 @@ def test_xy_chain_kernel_on_hardware():
     u = jax.random.uniform(key, (nx, ny, nz), dtype)
     v = jax.random.uniform(jax.random.fold_in(key, 1), (nx, ny, nz), dtype)
     faces = tuple(
-        jax.random.uniform(jax.random.fold_in(key, 2 + i), (k, ny, nz),
+        jax.random.uniform(jax.random.fold_in(key, 2 + i),
+                           (k, ny + 2 * hy, nz), dtype)
+        for i in range(4)
+    )
+    y_halos = tuple(
+        jax.random.uniform(jax.random.fold_in(key, 6 + i), (nx, hy, nz),
                            dtype)
         for i in range(4)
     )
     seeds = jnp.asarray([8, 4, 12], jnp.int32)
     # Interior shard in x and y of the 512^3 global grid.
-    offs = jnp.asarray([128, 128 - k, 0], jnp.int32)
+    offs = jnp.asarray([128, 128, 0], jnp.int32)
     row = jnp.int32(512)
 
     spec = _gs_spec()
     a = pallas_stencil.fused_step(
         (u, v), params, seeds, faces, spec=spec, use_noise=True, fuse=k,
-        offsets=offs, row=row,
+        offsets=offs, row=row, y_halos=y_halos,
+    )
+    planes = tuple(
+        jnp.concatenate([y_halos[2 * i], f, y_halos[2 * i + 1]], axis=1)
+        for i, f in enumerate((u, v))
     )
     b = pallas_stencil._xla_xchain_fallback(
-        (u, v), params, seeds, faces, spec=spec, fuse=k, use_noise=True,
-        offsets=offs, row=row,
+        planes, params, seeds, faces, spec=spec, fuse=k, use_noise=True,
+        offsets=offs.at[1].add(-hy), row=row,
     )
-    # Compare the y interior (the rows temporal.xy_chain consumes);
-    # pad rows carry ring values in both implementations but the
-    # comparison belongs on what downstream code reads.
+    # The kernel returns the owned rows; the fallback's halo rows carry
+    # ring values that no caller reads.
     np.testing.assert_allclose(
-        np.asarray(a[0][:, k:k + ny_int]), np.asarray(b[0][:, k:k + ny_int]),
+        np.asarray(a[0]), np.asarray(b[0][:, hy:hy + ny]),
         rtol=1e-4, atol=2e-6,
     )
     np.testing.assert_allclose(
-        np.asarray(a[1][:, k:k + ny_int]), np.asarray(b[1][:, k:k + ny_int]),
+        np.asarray(a[1]), np.asarray(b[1][:, hy:hy + ny]),
         rtol=1e-4, atol=2e-6,
     )
