@@ -5,7 +5,8 @@ or a Brusselator A/B sweep — members parametrize the run's registered
 model) used to cost N full launches; here the N parameter sets run as one compiled program:
 :class:`EnsembleSimulation` stacks a leading **member** axis onto the
 fields, params, and PRNG keys, and ``vmap``-s the *unchanged* per-member
-step body (``Simulation._local_run``) over it — stencil, in-jit noise,
+step body (``Simulation._local_run`` and the chain schedules of
+``parallel/schedule.py`` it drives) over it — stencil, in-jit noise,
 temporal-blocking chains, and the ``lax.ppermute`` halo exchange all
 batch through JAX's collective batching rules with zero ensemble-aware
 code in ``ops/`` or ``parallel/``. That is the point: the member axis

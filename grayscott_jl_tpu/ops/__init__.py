@@ -11,7 +11,8 @@ The two languages have *different* call contracts (deliberately — the
 Pallas kernel's whole advantage is consuming interior arrays + halo faces
 with in-kernel RNG, while the XLA kernel consumes ghost-padded arrays +
 a pre-generated noise field), so there is no uniform kernel callable:
-``Simulation._local_run`` branches on the language explicitly.
+the chain schedules (``parallel/schedule.py``) have one round function
+per language.
 ``validate_kernel_language`` front-loads the import/availability check so
 a bad config fails at construction, not at first ``iterate`` (the
 reference defers dispatch errors to runtime fallbacks,

@@ -355,33 +355,41 @@ def max_feasible_fuse_ypad(nx: int, ny: int, nz: int, itemsize: int,
     return 0
 
 
+def chain_depth_cap(local, dims, depth: int) -> int:
+    """The sharded chain round's geometry cap on ``depth`` for local
+    block ``local`` of mesh ``dims``: the exchanged slab is owned cells
+    (depth <= nx, and <= ny where y is sharded), and the z-band windows
+    need nz >= 2*depth where z is sharded."""
+    nx, ny, nz = local
+    cap = min(depth, nx)
+    if dims[1] > 1 or dims[2] > 1:
+        cap = min(cap, ny)
+    if dims[2] > 1:
+        cap = min(cap, nz // 2)
+    return cap
+
+
 def max_feasible_chain_depth(local, dims, itemsize: int, depth: int,
                              sublane: int = 8, mid_itemsize: int = None,
                              n_fields: int = 2) -> int:
     """Deepest in-kernel chain depth <= ``depth`` the SHARDED chain
-    dispatch for mesh ``dims`` can serve on local block ``local`` —
-    the runner's own geometry caps (x-chain: depth <= nx; xy-chain:
-    depth <= nx, ny, and nz // 2 when z is sharded) composed with the
-    VMEM slab ledger (:func:`max_feasible_fuse` /
-    :func:`max_feasible_fuse_ypad`). The ONE statement of chain-depth
-    feasibility shared by the s-step ``halo_depth`` gate
-    (``simulation.py``) and the autotune shortlist
-    (``tune/candidates.py``), so neither ever promises a depth the
-    kernel would decline; 0 when not even depth 1 fits."""
-    nx, ny, nz = local
-    if dims[1] == 1 and dims[2] == 1:
-        cap = min(depth, nx)
-        if cap < 1:
-            return 0
-        return max_feasible_fuse(nx, ny, nz, itemsize, cap,
-                                 mid_itemsize=mid_itemsize,
-                                 n_fields=n_fields)
-    cap = min(depth, nx, ny)
-    if dims[2] > 1:
-        cap = min(cap, nz // 2)
+    round (``parallel/schedule.pallas_sharded_round``) can serve for
+    mesh ``dims`` on local block ``local`` — its geometry cap
+    (:func:`chain_depth_cap`) composed with the VMEM slab ledger
+    (:func:`max_feasible_fuse`, or :func:`max_feasible_fuse_ypad`
+    where the kernel takes y halos). The ONE statement of chain-depth
+    feasibility shared by the runner's schedule, the s-step
+    ``halo_depth`` gate (``simulation.py``) and the autotune shortlist
+    (``tune/candidates.py``), so none ever promises a depth the kernel
+    would decline; 0 when not even depth 1 fits."""
+    cap = chain_depth_cap(local, dims, depth)
     if cap < 1:
         return 0
-    return max_feasible_fuse_ypad(nx, ny, nz, itemsize, cap, sublane,
+    if dims[1] == 1 and dims[2] == 1:
+        return max_feasible_fuse(*local, itemsize, cap,
+                                 mid_itemsize=mid_itemsize,
+                                 n_fields=n_fields)
+    return max_feasible_fuse_ypad(*local, itemsize, cap, sublane,
                                   mid_itemsize=mid_itemsize,
                                   n_fields=n_fields)
 
@@ -1003,7 +1011,7 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
     ``variant`` names the launch in the kernel's stamp (``gs_kernel``
     in its ``kernel_metadata``, ``gs_<variant>`` its name): by default
     ``chain`` without faces, ``faces`` with the 6n-tuple, ``x_chain``
-    with the 2n-tuple; the xy-chain's caller passes ``xy_chain``.
+    with the 2n-tuple, and ``xy_chain`` with ``y_halos``.
 
     ``fuse=k`` temporal blocking advances k steps per HBM pass
     (single- or multi-block; with faces only in the 2n-tuple x-chain
@@ -1050,8 +1058,8 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
     if x_chain and fuse < 2:
         raise ValueError("the x-chain faces mode requires fuse >= 2")
     if variant is None:
-        variant = ("x_chain" if x_chain else "chain" if faces is None
-                   else "faces")
+        variant = ("xy_chain" if y_halos is not None else "x_chain"
+                   if x_chain else "chain" if faces is None else "faces")
     nx, ny, nz = fields[0].shape
     dtype = fields[0].dtype
     on_tpu = jax.default_backend() == "tpu"
@@ -1143,13 +1151,13 @@ def fused_step(fields, params, seeds, faces=None, *, spec, use_noise=True,
                 # correctness issue — make it visible (the module's
                 # stated invariant: benchmark users must see when
                 # "Pallas" is measuring the XLA kernel). Callers should
-                # cap the chain depth with max_feasible_fuse so the
-                # exchange width matches a depth Mosaic can serve.
+                # cap the chain depth with max_feasible_chain_depth so
+                # the exchange width matches a depth Mosaic can serve.
                 _warn_once(
                     f"x-chain fuse={fuse} does not fit VMEM for local "
                     f"grid {nx}x{ny_plane}x{nz} ({dtype}); running the "
                     "XLA x-chain fallback — cap the depth with "
-                    "max_feasible_fuse or max_feasible_fuse_ypad"
+                    "max_feasible_chain_depth"
                 )
             if not hy:
                 return _xla_xchain_fallback(

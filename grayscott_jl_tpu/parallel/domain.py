@@ -24,6 +24,9 @@ import os
 from ..config.env import env_str
 from typing import List, Tuple
 
+#: Mesh axis names, one per grid axis (the ``MPI.Cart_create`` dims).
+AXIS_NAMES = ("x", "y", "z")
+
 
 def dims_create(nnodes: int, ndims: int = 3) -> Tuple[int, ...]:
     """Balanced factorization of ``nnodes`` into ``ndims`` dims.

@@ -21,10 +21,10 @@ Split-phase exchange (round 6, docs/OVERLAP.md): the fused helpers above
 produce data the *whole* kernel pass depends on, which serializes
 ppermute latency in front of the compute. :func:`start_exchange` /
 :class:`PendingExchange` issue the same ppermutes with NO consumer on the
-interior compute's dataflow path, and :func:`frozen_frame` /
-:func:`frozen_slabs` build the constant stand-ins the interior pass reads
-instead — so XLA's async collective-permute + latency-hiding scheduler
-can run the ICI transfer under the interior work, and the arrived halos
+interior compute's dataflow path, and :func:`frozen_frame` builds the
+constant stand-in the interior pass reads instead — so XLA's async
+collective-permute + latency-hiding scheduler can run the ICI transfer
+under the interior work, and the arrived halos
 feed only the thin boundary-band recompute that is stitched afterwards
 (``parallel/temporal.stitch_bands_from_frame``). Under JAX there is no
 imperative wait: "start" means *issued with no dependency on the interior
@@ -191,27 +191,6 @@ def halo_pad_wide(
     return tuple(padded)
 
 
-def exchange_x_slabs(
-    arrays: Sequence[jnp.ndarray],
-    boundary_values: Sequence[float],
-    ax: str,
-    n: int,
-    width: int,
-) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-    """``width``-wide (lo, hi) x-slab halos for each array.
-
-    The 1D-x-sharded in-kernel temporal chain's exchange: ONE ppermute
-    per direction carries a ``width``-plane slab of all arrays (stacked),
-    feeding ``width`` fused kernel steps from a single exchange round —
-    2 collectives per k steps where the reference exchanges 6 faces
-    every step (``communication.jl:138-199``). Global-edge shards get
-    the frozen boundary constant. Must be called inside ``shard_map``.
-    (The width-generalized form of the per-axis exchange every other
-    path uses — one implementation, ``_exchange_dim``.)
-    """
-    return _exchange_dim(list(arrays), boundary_values, 0, ax, n, width)
-
-
 def exchange_slabs(
     arrays: Sequence[jnp.ndarray],
     boundary_values: Sequence[float],
@@ -220,33 +199,18 @@ def exchange_slabs(
     n: int,
     width: int,
 ) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-    """``width``-wide (lo, hi) boundary slabs along any one mesh axis —
-    the axis-generic form of :func:`exchange_x_slabs` (one ppermute per
-    direction carries all arrays; global-edge shards get the frozen
-    boundary constant). The xy-chain exchanges its y halos with this
-    before exchanging x slabs of the y-padded fields, so the x slabs
-    carry the y corner data the in-kernel ring recompute needs. Must be
-    called inside ``shard_map``."""
+    """``width``-wide (lo, hi) boundary slabs along any one mesh axis
+    (one ppermute per direction carries all arrays; global-edge shards
+    get the frozen boundary constant). The in-kernel temporal chain's
+    exchange (``parallel/temporal.xy_chain``): one round of
+    ``width``-wide slabs feeds ``width`` fused kernel steps — 2
+    collectives per k steps on an (n, 1, 1) mesh, where the reference
+    exchanges 6 faces every step (``communication.jl:138-199``). Where
+    y is sharded it exchanges y halos first, then x slabs of the
+    y-padded fields, so the x slabs carry the y corner data the
+    in-kernel ring recompute needs. Must be called inside
+    ``shard_map``."""
     return _exchange_dim(list(arrays), boundary_values, dim, ax, n, width)
-
-
-def frozen_slabs(
-    arrays: Sequence[jnp.ndarray],
-    boundary_values: Sequence[float],
-    dim: int,
-    width: int,
-) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Constant (lo, hi) ``width``-thick slabs at the frozen boundary
-    value for each array — the shape-compatible stand-in the split-phase
-    interior pass consumes instead of exchanged slabs (identical to what
-    an edge shard, or a single-shard axis, resolves to)."""
-    out = []
-    for a, bv in zip(arrays, boundary_values):
-        shape = list(a.shape)
-        shape[dim] = width
-        f = jnp.full(shape, bv, a.dtype)
-        out.append((f, f))
-    return out
 
 
 def frozen_frame(

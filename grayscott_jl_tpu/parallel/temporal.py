@@ -1,4 +1,5 @@
-"""Cross-shard temporal blocking for the sharded Pallas path.
+"""Cross-shard temporal blocking: the chain rounds that
+``parallel/schedule.py`` drives on sharded blocks.
 
 The fused Pallas kernel (``ops/pallas_stencil.py``) chains ``k``
 timesteps per HBM pass by walking shrinking windows along its leading
@@ -6,8 +7,9 @@ timesteps per HBM pass by walking shrinking windows along its leading
 data on every sharded axis — and what that costs depends on which
 *Mosaic tiling dimension* the axis lands on:
 
-* **x** (untiled leading dim): free — the x-chain mode consumes k-wide
-  exchanged x slabs directly (round 3);
+* **x** (untiled leading dim): free — the kernel's x-chain mode
+  consumes k-wide exchanged x slabs directly, and on an (n, 1, 1) mesh
+  that is all :func:`xy_chain` exchanges;
 * **y** (sublane dim, 8/16-granularity): cheap — :func:`xy_chain`
   hands the kernel k-deep exchanged y halos (each rounded up to the
   sublane tile with boundary-constant filler rows), which it places
@@ -44,9 +46,10 @@ into exchanging once per ``halo_depth`` chain rounds — a
 region shrinks one cell per side per step until the next exchange
 restores full width. Because :func:`window_chain` shrinks uniformly,
 composing ``k`` depth-``d`` segments on the shared frame is the SAME
-program as one depth-``k*d`` chain — the realization ``simulation.py``
-uses — so ``halo_depth=k`` at chain depth ``d`` is bitwise identical
-to ``halo_depth=1`` at chain depth ``k*d``, and the split-phase form
+program as one depth-``k*d`` chain — the realization
+``parallel/schedule.py`` uses — so ``halo_depth=k`` at chain depth
+``d`` is bitwise identical to ``halo_depth=1`` at chain depth ``k*d``,
+and the split-phase form
 (:func:`stitch_bands_from_frame` after an interior pass on a frozen
 frame) composes with it unchanged: the deeper transfer hides behind
 proportionally more interior steps.
@@ -198,7 +201,7 @@ def xy_overlap_feasible(local, dims, depth) -> bool:
     if dims[2] > 1:
         return True
     k = depth
-    return not ((dims[0] > 1 and local[0] < 2 * k) or local[1] < 2 * k)
+    return not any(dims[d] > 1 and local[d] < 2 * k for d in (0, 1))
 
 
 def xy_chain(
@@ -213,7 +216,11 @@ def xy_chain(
     Model-generic: ``fields`` is the model's field tuple in declaration
     order, and every faces tuple is field-major (lo, hi) pairs — the
     generated kernel's x-chain operand order
-    (``ops/pallas_stencil.fused_step``). The s-step exchange schedule
+    (``ops/pallas_stencil.fused_step``). Where neither y nor z is
+    sharded (an (n, 1, 1) mesh) the round exchanges no y slabs and the
+    kernel takes no y halos: its plane is the block's own ``ny`` rows
+    (the ``gs_x_chain`` launch), and the exchange is 2 ppermutes. The
+    s-step exchange schedule
     (``halo_depth=k``, docs/TEMPORAL.md) reuses this round unchanged at
     ``depth = fuse*k`` — one k-times-deeper ``halo_pad_wide`` frame per
     round, the same 6 (z-sharded) or 4 collectives, amortized over k
@@ -224,9 +231,10 @@ def xy_chain(
     blocks, in place: ``y_halos`` are the per-field (lo, hi) blocks of
     ``pallas_stencil.y_halo_rows(depth, sublane)`` rows each (the
     ``depth`` exchanged rows next to the owned ones, boundary-constant
-    filler beyond), and the x faces span the same rows as the kernel's
-    VMEM plane (``fused_step``'s xy-chain form). So the round neither
-    pads the fields in y nor slices the result.
+    filler beyond), or None on an (n, 1, 1) mesh, and the x faces span
+    the same rows as the kernel's VMEM plane (``fused_step``'s xy-chain
+    form). So the round neither pads the fields in y nor slices the
+    result.
     ``unit_noise(step_idx, origin, shape)`` draws from the shared
     position-keyed stream. Must be called inside ``shard_map``.
 
@@ -255,8 +263,12 @@ def xy_chain(
     dtype = fields[0].dtype
     dims = axis_sizes
     k = depth
-    hy = y_halo_rows(k, sublane)
     z_sharded = dims[2] > 1
+    # Rows of y halo above and below the owned rows in the kernel's
+    # plane: ky exchanged rows, then fy rows of filler.
+    hy = y_halo_rows(k, sublane) if dims[1] > 1 or z_sharded else 0
+    ky = k if hy else 0
+    fy = hy - ky
     if overlap and not xy_overlap_feasible(fields[0].shape, dims, k):
         overlap = False  # no comm-independent interior: fused round
     if overlap and band_kernel is None:
@@ -346,22 +358,24 @@ def xy_chain(
         # fields alive past it with a whole-field copy).
         c = 2 * k if overlap else k
         ye = tuple(jnp.concatenate([f[:, :c], f[:, ny - c:]], axis=1)
-                   for f in fields)
+                   for f in fields) if hy else ()
         xe = tuple(jnp.concatenate([f[:c], f[nx - c:]]) for f in fields)
         ye, xe, fields = lax.optimization_barrier((ye, xe, fields))
-        y_pairs = halo.exchange_slabs(
-            list(ye), bvs, 1, axis_names[1], dims[1], k
-        )
-        # The x ends of the y-padded fields [y lo | field | y hi]: the
-        # first and last c planes, (2c, ny + 2k, nz).
-        xe_pr = tuple(
-            jnp.concatenate([jnp.concatenate([lo[:c], lo[nx - c:]]), e,
-                             jnp.concatenate([hi[:c], hi[nx - c:]])],
-                            axis=1)
-            for e, (lo, hi) in zip(xe, y_pairs)
-        )
+        if hy:
+            y_pairs = halo.exchange_slabs(
+                list(ye), bvs, 1, axis_names[1], dims[1], k
+            )
+            # The x ends of the y-padded fields [y lo | field | y hi]:
+            # the first and last c planes, (2c, ny + 2k, nz).
+            xe = tuple(
+                jnp.concatenate([jnp.concatenate([lo[:c], lo[nx - c:]]),
+                                 e,
+                                 jnp.concatenate([hi[:c], hi[nx - c:]])],
+                                axis=1)
+                for e, (lo, hi) in zip(xe, y_pairs)
+            )
         x_pairs = halo.exchange_slabs(
-            list(xe_pr), bvs, 0, axis_names[0], dims[0], k
+            list(xe), bvs, 0, axis_names[0], dims[0], k
         )
         if overlap and dims[1] > 1:
             # y bands: body rows are [arrived y slab | 2k owned rows]
@@ -388,27 +402,30 @@ def xy_chain(
                 ))
         if overlap and dims[0] > 1:
             # x bands: a k-plane body whose x faces are the arrived
-            # slab and the adjacent owned planes (both y-padded).
+            # slab and the adjacent owned planes (both y-padded where
+            # y is sharded).
             for body, faces_b, d_x in (
-                (tuple(e[:k] for e in xe_pr),
+                (tuple(e[:k] for e in xe),
                  interleave(tuple(lo for lo, _ in x_pairs),
-                            tuple(e[k:2 * k] for e in xe_pr)),
+                            tuple(e[k:2 * k] for e in xe)),
                  0),
-                (tuple(e[3 * k:] for e in xe_pr),
-                 interleave(tuple(e[2 * k:3 * k] for e in xe_pr),
+                (tuple(e[3 * k:] for e in xe),
+                 interleave(tuple(e[2 * k:3 * k] for e in xe),
                             tuple(hi for _, hi in x_pairs)),
                  nx - k),
             ):
                 band_jobs.append((
                     body, faces_b,
-                    jnp.stack([offs[0] + d_x, offs[1] - k, offs[2]]),
-                    slice(k, k + ny), (d_x, 0, 0),
+                    jnp.stack([offs[0] + d_x,
+                               offs[1] - ky if ky else offs[1], offs[2]]),
+                    slice(ky, ky + ny), (d_x, 0, 0),
                 ))
 
+    y_halos = None
     if overlap:
-        y_halos = tuple(
-            jnp.full((nx, hy, nz), bv, dtype) for bv in bvs for _ in (0, 1)
-        )
+        if hy:
+            y_halos = tuple(jnp.full((nx, hy, nz), bv, dtype)
+                            for bv in bvs for _ in (0, 1))
         faces = tuple(
             jnp.full((k, ny + 2 * hy, nz), bv, dtype)
             for bv in bvs for _ in (0, 1)
@@ -417,12 +434,13 @@ def xy_chain(
         # The exchanged rows next to the owned ones, boundary-constant
         # filler beyond them: every halo is hy rows, whole sublane
         # tiles, and the x faces span the kernel's whole plane.
-        y_halos = tuple(
-            h for (lo, hi), bv in zip(y_pairs, bvs)
-            for h in (fill_y(lo, bv, hy - k, 0), fill_y(hi, bv, 0, hy - k))
-        )
+        if hy:
+            y_halos = tuple(
+                h for (lo, hi), bv in zip(y_pairs, bvs)
+                for h in (fill_y(lo, bv, fy, 0), fill_y(hi, bv, 0, fy))
+            )
         faces = tuple(
-            fill_y(f, bv, hy - k, hy - k)
+            fill_y(f, bv, fy, fy)
             for pair, bv in zip(x_pairs, bvs) for f in pair
         )
     out = chain_kernel(fields, y_halos, faces, step, offs)

@@ -50,13 +50,11 @@ from .config.env import env_raw, env_str
 from .config.settings import Settings
 from .models import get_model
 from .ops import noise as noise_ops
-from .ops import stencil, validate_kernel_language
-from .parallel import halo, temporal
-from .parallel.domain import CartDomain
+from .ops import validate_kernel_language
+from .parallel import schedule, temporal
+from .parallel.domain import AXIS_NAMES, CartDomain
 from .obs.trace import get_tracer
 from .utils.log import _is_primary
-
-AXIS_NAMES = ("x", "y", "z")
 
 
 def _gs_step(fn):
@@ -1040,7 +1038,7 @@ class Simulation:
     # ---------------------------------------------------------------- runner
 
     def _local_run(self, *args, nsteps: int):
-        """``nsteps`` fused steps on one (local) block. Called directly on a
+        """``nsteps`` steps on one (local) block. Called directly on a
         single device, or per-shard under ``shard_map``.
 
         ``args`` is the model's field tuple (declaration order) followed
@@ -1048,480 +1046,70 @@ class Simulation:
         what makes the runner model-generic (one field for heat, two for
         Gray-Scott/Brusselator/FHN, n for anything registered).
 
+        The chain schedule — which round, at what depth — is
+        ``parallel/schedule.select``'s; this builds the block's offsets
+        and its pin and noise closures, and runs the rounds.
+
         Noise everywhere comes from the position-keyed stream
         (``ops/noise.py``): one shared key, absolute step index, global
         cell coordinates — so the trajectory is invariant under step
         chunking, shard layout, and temporal fusion.
         """
         *fields, base_key, step0, params = args
-        fields = tuple(fields)
-        model = self.model
-        use_noise = self.use_noise
-        sharded = self.sharded
-        dims = self.domain.dims
-        L = self.settings.L
-        boundaries = model.boundaries
+        model, L = self.model, self.settings.L
         dtype = fields[0].dtype
-        # bf16_f32acc accumulation dtype (docs/PRECISION.md): None-like
-        # (equal to the storage dtype) on every other posture, so the
-        # default paths trace the historical graph bit for bit.
-        cdt = self.compute_dtype
-        key_i32 = lax.bitcast_convert_type(base_key, jnp.int32)
-
-        if sharded:
-            block = self.domain.local_shape
-            offs = jnp.stack(
-                [
-                    lax.axis_index(ax) * jnp.int32(b)
-                    for ax, b in zip(AXIS_NAMES, block)
-                ]
-            )
+        key = lax.bitcast_convert_type(base_key, jnp.int32)
+        if self.sharded:
+            offs = jnp.stack([
+                lax.axis_index(ax) * jnp.int32(b)
+                for ax, b in zip(AXIS_NAMES, self.domain.local_shape)
+            ])
         else:
             offs = jnp.zeros((3,), jnp.int32)
+        padded = self.sharded and self.domain.padded
 
-        padded = sharded and self.domain.padded
-        overlap_on = self.comm_overlap
-
-        def pin_block(fields):
+        def pin(fields):
             """Re-pin each block's pad cells (global coords >= L) to the
             field's boundary value — required after every chain round
             with non-divisible L: the chain's final stage writes them
             unpinned, and the next round's stencil reads them as the
             frozen ghost shell."""
-            fields = tuple(fields)
             if not padded:
-                return fields
+                return tuple(fields)
             return tuple(
                 temporal.pin_out_of_domain(f, bv, offs, L)
-                for f, bv in zip(fields, boundaries)
+                for f, bv in zip(fields, model.boundaries)
             )
 
         def unit_noise(step_idx, offsets, shape):
             return noise_ops.uniform_pm1_block(
-                key_i32, step_idx, offsets, shape, L, dtype
+                key, step_idx, offsets, shape, L, dtype
             )
 
-        def run_chain_rounds(chain, fuse, fields):
-            """Drive ``nsteps`` as full-depth chain rounds plus a
-            shallower remainder chain — the shared loop of all three
-            temporal-blocking paths (1D x-chain, 3D Pallas chain,
-            sharded XLA chain). ``chain(fields, step, depth)`` maps the
-            field tuple through one exchange-plus-depth-steps round."""
-
-            def chain_body(i, carry):
-                return chain(carry, step0 + fuse * i, fuse)
-
-            rounds, rem = divmod(nsteps, fuse)
-            fields = lax.fori_loop(0, rounds, chain_body, fields)
-            if rem:
-                fields = chain(fields, step0 + fuse * rounds, rem)
-            return fields
-
-        if self.kernel_language == "pallas":
-            # The fused kernel is GENERATED from the model declaration
-            # (ops/kernelgen): the gate in __init__ guarantees the
-            # model's reaction trace-inlines, and the spec is the jit
-            # static argument every launch below shares.
-            from .ops import kernelgen, pallas_stencil
-
-            spec = kernelgen.get_spec(model)
-            n_f = spec.n_fields
-
-            def step_seeds(step_idx):
-                return jnp.stack(
-                    [key_i32[0], key_i32[1], jnp.asarray(step_idx, jnp.int32)]
-                )
-
+        round_fn, depth = schedule.select(
+            self.kernel_language, self.sharded, nsteps=nsteps,
+            fuse=self._fuse_base(), halo_depth=self.halo_depth,
+            local=self.domain.local_shape, dims=self.domain.dims,
+            dtype=self.dtype, n_fields=model.n_fields,
+            overlap=self.comm_overlap,
+        )
+        blk = schedule.Block(
+            model=model, params=params, key=key, offs=offs, row=L,
+            dims=self.domain.dims, use_noise=self.use_noise,
+            unit_noise=unit_noise, pin=pin,
+            compute_dtype=self.compute_dtype, overlap=self.comm_overlap,
+            padded=padded,
             # Concurrent interpret-mode kernels deadlock under shard_map
-            # (global interpreter state) — sharded CPU runs take the XLA
-            # fallback inside fused_step; real TPU runs the fused kernel.
-            # Ensemble bodies run under vmap, where interpret mode is a
-            # liability too (per-member re-interpretation): the XLA
-            # fallback is the same elementwise program, bitwise.
-            allow_interpret = not sharded and not self.is_ensemble
-
-            def kernel_step(fields_k, step_idx, faces):
-                return pallas_stencil.fused_step(
-                    fields_k, params, step_seeds(step_idx), faces,
-                    spec=spec, use_noise=use_noise,
-                    allow_interpret=allow_interpret,
-                    fuse=1, offsets=offs, row=L,
-                )
-
-            if sharded and dims[1] == 1 and dims[2] == 1:
-                # 1D x-sharded mesh (GS_TPU_MESH_DIMS=n,1,1): the ONLY
-                # shard boundaries are x faces — the kernel's natural
-                # element (leading-dim slabs, no lane-alignment issue) —
-                # so the in-kernel fused chain runs ACROSS the shard
-                # boundary: one 2-ppermute exchange of k-wide x slabs
-                # feeds one fuse=k kernel launch per chain. Unlike the
-                # general 3D chain below (single-step kernel stages +
-                # XLA ghost advance), every sharded step here runs at
-                # the fused single-chip schedule — the fastest
-                # pod-slice layout for the Pallas language (<=16 chips;
-                # at higher counts the 1D surface/volume ratio loses to
-                # 3D, see BASELINE.md's ICI projection).
-                fuse = min(
-                    self._fuse_base(), max(nsteps, 1),
-                    self.domain.local_shape[0],
-                )
-                if self.halo_depth > 1:
-                    # Communication-avoiding s-step schedule
-                    # (docs/TEMPORAL.md): the exchange round carries a
-                    # (fuse x halo_depth)-deep slab pair and the
-                    # in-kernel chain walks all of it before the next
-                    # exchange — the EXACT program a halo_depth=1
-                    # chain at depth fuse*halo_depth lowers to, so
-                    # k at depth d is bitwise identical to k=1 at
-                    # depth k*d. Feasibility was gated at
-                    # construction; nsteps still bounds the final
-                    # round, and the VMEM cap below re-checks the
-                    # realized depth.
-                    fuse = min(
-                        fuse * self.halo_depth, max(nsteps, 1),
-                        self.domain.local_shape[0],
-                    )
-                # The exchange width must match a chain depth the
-                # Mosaic kernel can actually serve — an infeasible
-                # depth would silently run every step on the XLA
-                # fallback (e.g. the v5p-16 pod shape 64x512x512 f32
-                # fits fuse=3, not 5). Depth 1 falls through to the
-                # 12-face single-step exchange below.
-                feasible = pallas_stencil.max_feasible_fuse(
-                    *self.domain.local_shape,
-                    jnp.dtype(self.dtype).itemsize, fuse,
-                    mid_itemsize=pallas_stencil.mid_itemsize_for(
-                        self.dtype
-                    ),
-                    n_fields=n_f,
-                )
-                if feasible < fuse:
-                    capped = max(feasible, 1)
-                    pallas_stencil._warn_once(
-                        f"x-chain depth capped at {capped} "
-                        f"(fuse={fuse} does not fit VMEM for local grid "
-                        f"{self.domain.local_shape})"
-                    )
-                    fuse = capped
-
-                def chain(fields_c, step, depth):
-                    if depth == 1:
-                        faces_full = halo.exchange_faces(
-                            fields_c, boundaries, AXIS_NAMES, dims
-                        )
-                        return pin_block(
-                            kernel_step(fields_c, step, faces_full)
-                        )
-                    if overlap_on and fields_c[0].shape[0] >= 2 * depth:
-                        # Split-phase round (docs/OVERLAP.md): the same
-                        # 2-ppermute slab exchange is issued first, but
-                        # the kernel chains on frozen-constant x faces
-                        # — no data dependency on the collectives — and
-                        # the arrived slabs feed only the two k-thick x
-                        # bands stitched afterwards. Each band is the
-                        # SAME chain program (the x-chain XLA reference,
-                        # ``_xla_xchain_fallback``) on a k-plane body
-                        # whose x faces are the arrived slab and the
-                        # adjacent owned planes — same structure, same
-                        # per-cell op order, so XLA's codegen cannot
-                        # drift a ulp between the fused and split
-                        # lowerings. Blocks shallower than 2k have no
-                        # interior to hide behind and take the fused
-                        # round below.
-                        self.overlap_applied = True
-                        k = depth
-                        nx = fields_c[0].shape[0]
-                        # The launch updates fields_c in place, so all
-                        # else the round reads of it (the slabs the
-                        # exchange sends, the bands' planes) is cut out
-                        # first: the 2k planes at each end, one 4k-plane
-                        # buffer per field, which the barrier keeps
-                        # ahead of the launch — else XLA keeps fields_c
-                        # alive past it with a full copy.
-                        ends = tuple(
-                            jnp.concatenate([f[:2 * k], f[nx - 2 * k:]])
-                            for f in fields_c
-                        )
-                        ends, fields_c = lax.optimization_barrier(
-                            (ends, fields_c)
-                        )
-                        pairs = halo.exchange_x_slabs(
-                            ends, boundaries, AXIS_NAMES[0], dims[0], k
-                        )
-                        faces_z = tuple(
-                            f for fs in halo.frozen_slabs(
-                                fields_c, boundaries, 0, k
-                            ) for f in fs
-                        )
-                        interior = list(pallas_stencil.fused_step(
-                            fields_c, params, step_seeds(step), faces_z,
-                            spec=spec, use_noise=use_noise,
-                            allow_interpret=allow_interpret,
-                            fuse=k, offsets=offs, row=L,
-                        ))
-                        # Band faces stay field-major (lo, hi): the low
-                        # band reads the arrived lo slab and the owned
-                        # planes above it, the high band mirrors that.
-                        jobs = (
-                            (tuple(e[:k] for e in ends),
-                             tuple(x for e, (lo, _hi) in zip(ends, pairs)
-                                   for x in (lo, e[k:2 * k])),
-                             0),
-                            (tuple(e[3 * k:] for e in ends),
-                             tuple(x for e, (_lo, hi) in zip(ends, pairs)
-                                   for x in (e[2 * k:3 * k], hi)),
-                             nx - k),
-                        )
-                        for body_f, faces_b, d_x in jobs:
-                            band = pallas_stencil._xla_xchain_fallback(
-                                body_f, params, step_seeds(step),
-                                faces_b, spec=spec, fuse=k,
-                                use_noise=use_noise,
-                                offsets=jnp.stack([
-                                    offs[0] + d_x, offs[1], offs[2],
-                                ]),
-                                row=L,
-                            )
-                            interior = [
-                                lax.dynamic_update_slice(
-                                    fi, bi, (d_x, 0, 0)
-                                )
-                                for fi, bi in zip(interior, band)
-                            ]
-                        return pin_block(tuple(interior))
-                    pairs = halo.exchange_x_slabs(
-                        fields_c, boundaries, AXIS_NAMES[0], dims[0], depth
-                    )
-                    faces_x = tuple(f for pr in pairs for f in pr)
-                    return pin_block(pallas_stencil.fused_step(
-                        fields_c, params, step_seeds(step), faces_x,
-                        spec=spec, use_noise=use_noise,
-                        allow_interpret=allow_interpret,
-                        fuse=depth, offsets=offs, row=L,
-                    ))
-
-                return run_chain_rounds(chain, fuse, fields)
-
-            if sharded:
-                # xy-chain (+ z-band correction when z is sharded): the
-                # in-kernel k-deep chain crosses x AND y shard
-                # boundaries (y is the cheap sublane dim), so every
-                # sharded stage runs at the fused single-chip schedule;
-                # only sharded-z sides pay a thin XLA band recompute
-                # (``parallel/temporal.xy_chain``). One exchange round
-                # per k steps, like the XLA language's chain.
-                block = self.domain.local_shape
-                cap = [block[0], block[1]]
-                if dims[2] > 1:
-                    # z-band windows need local nz >= 2*depth.
-                    cap.append(block[2] // 2)
-                # Floor of 1: a cap of 0 (local nz == 1 on a z-sharded
-                # mesh) must degrade to the depth-1 12-face path, not
-                # divide by zero in run_chain_rounds.
-                fuse = max(1, min(self._fuse_base(), max(nsteps, 1), *cap))
-                if self.halo_depth > 1:
-                    # s-step exchange (docs/TEMPORAL.md): deepen the
-                    # in-kernel chain to fuse*halo_depth — one
-                    # (fuse x halo_depth)-deep ``halo_pad_wide`` frame
-                    # per round, same program as halo_depth=1 at the
-                    # product depth, so the round count (and the
-                    # collective count with it) drops by halo_depth.
-                    fuse = max(1, min(
-                        fuse * self.halo_depth, max(nsteps, 1), *cap,
-                    ))
-                sublane = 16 if self.dtype == jnp.bfloat16 else 8
-                feasible = pallas_stencil.max_feasible_fuse_ypad(
-                    *block, jnp.dtype(self.dtype).itemsize, fuse, sublane,
-                    mid_itemsize=pallas_stencil.mid_itemsize_for(
-                        self.dtype
-                    ),
-                    n_fields=n_f,
-                )
-                if feasible < fuse:
-                    pallas_stencil._warn_once(
-                        f"xy-chain depth capped at {max(feasible, 1)} "
-                        f"(fuse={fuse} does not fit VMEM for local grid "
-                        f"{block} with its y halo)"
-                    )
-                    fuse = max(feasible, 1)
-
-                def chain(fields_c, step, depth):
-                    if depth == 1:
-                        faces_full = halo.exchange_faces(
-                            fields_c, boundaries, AXIS_NAMES, dims
-                        )
-                        return pin_block(
-                            kernel_step(fields_c, step, faces_full)
-                        )
-
-                    def chain_kernel(fields_k, y_halos, faces_k, stp,
-                                     offs_k):
-                        return pallas_stencil.fused_step(
-                            fields_k, params, step_seeds(stp), faces_k,
-                            spec=spec, use_noise=use_noise,
-                            allow_interpret=allow_interpret,
-                            fuse=depth, offsets=offs_k, row=L,
-                            variant="xy_chain", y_halos=y_halos,
-                        )
-
-                    def band_kernel(fields_b, faces_b, stp, offs_b):
-                        # The x-chain XLA reference — the SAME program
-                        # structure as the fused kernel's own fallback,
-                        # which keeps recomputed bands bitwise equal.
-                        return pallas_stencil._xla_xchain_fallback(
-                            fields_b, params, step_seeds(stp), faces_b,
-                            spec=spec, fuse=depth, use_noise=use_noise,
-                            offsets=offs_b, row=L,
-                        )
-
-                    ov = overlap_on and temporal.xy_overlap_feasible(
-                        block, dims, depth
-                    )
-                    if ov:
-                        self.overlap_applied = True
-                    return pin_block(temporal.xy_chain(
-                        fields_c, params, model, depth=depth, step=step,
-                        offs=offs, chain_kernel=chain_kernel,
-                        use_noise=use_noise, unit_noise=unit_noise,
-                        row=L, axis_names=AXIS_NAMES, axis_sizes=dims,
-                        boundaries=boundaries, sublane=sublane,
-                        overlap=ov, band_kernel=band_kernel,
-                    ))
-
-                return run_chain_rounds(chain, fuse, fields)
-
-            # Single block: in-kernel temporal blocking (``fuse`` steps
-            # per HBM pass — the slab pipeline is DMA-envelope-bound on
-            # the v5e, so per-step time scales ~1/fuse); the noise stream
-            # is keyed on absolute (step, cell), so fusion/chunking does
-            # not change the trajectory.
-            fuse = min(self._fuse_base(), max(nsteps, 1))
-
-            def body(i, carry):
-                return pallas_stencil.fused_step(
-                    carry, params, step_seeds(step0 + fuse * i), None,
-                    spec=spec, use_noise=use_noise,
-                    allow_interpret=allow_interpret,
-                    fuse=fuse, offsets=offs, row=L,
-                )
-
-            rounds, rem = divmod(nsteps, fuse)
-            fields = lax.fori_loop(0, rounds, body, fields)
-            if rem:
-                fields = pallas_stencil.fused_step(
-                    fields, params, step_seeds(step0 + fuse * rounds),
-                    None, spec=spec, use_noise=use_noise,
-                    allow_interpret=allow_interpret,
-                    fuse=rem, offsets=offs, row=L,
-                )
-            return tuple(fields)
-
-        # ---- XLA kernel path ----
-
-        def single_step(i, carry):
-            if sharded:
-                fields_pad = halo.halo_pad(
-                    carry, boundaries, AXIS_NAMES, dims
-                )
-            else:
-                fields_pad = tuple(
-                    stencil.pad_with_boundary(f, bv)
-                    for f, bv in zip(carry, boundaries)
-                )
-            if use_noise:
-                nz = params.noise * unit_noise(
-                    step0 + i, offs, carry[0].shape
-                )
-            else:
-                nz = jnp.asarray(0.0, dtype)
-            return pin_block(
-                stencil.reaction_update(fields_pad, nz, params, model,
-                                        compute_dtype=cdt)
-            )
-
-        # Split-phase gate for the XLA window mode: only band windows
-        # thin along the LEADING (x) axis are codegen-stable on XLA:CPU
-        # — shrinking a trailing extent is exactly the shape change its
-        # FP-contraction decisions key on (measured: x-thin frame
-        # windows reproduce the full window chain bitwise through k=4;
-        # y- and z-thin windows drift 1 ulp at some shapes). So the
-        # window mode overlaps 1D x-sharded meshes; multi-axis meshes
-        # take the fused round here and get their overlap through the
-        # Pallas chains, whose band recomputes share the kernel
-        # fallback's structure (and whose z bands are identical in both
-        # modes). docs/OVERLAP.md "Bitwise-identity guarantee".
-        overlap_xla = overlap_on and dims[1] == 1 and dims[2] == 1
-
-        if not sharded or (nsteps < 2 and not overlap_xla):
-            return lax.fori_loop(0, nsteps, single_step, fields)
-
-        # Sharded temporal blocking: ONE width-k halo exchange feeds k
-        # steps — stage s recomputes step n+1+s on a window extending
-        # (k-1-s) cells beyond the block (neighbor-owned ring cells
-        # reproduce the owner's values bitwise: same inputs via the
-        # corner-propagated halo, same position-keyed noise), and the
-        # shrinking ring doubles as the next stage's ghost shell. Cuts
-        # the exchange count per step by k (the cost
-        # ``communication.jl:138-199`` pays every step). The chain body
-        # is ``temporal.window_chain`` on the exchanged frame — the same
-        # shrinking-window program the band recomputes use, which is
-        # what makes the split-phase stitch bitwise.
-        fuse = min(self._fuse_base(), nsteps, min(self.domain.local_shape))
-        if self.halo_depth > 1:
-            # Communication-avoiding s-step schedule (docs/TEMPORAL.md):
-            # one exchange round carries a (fuse x halo_depth)-deep
-            # frame and the shrinking-window chain advances all of it
-            # before the next exchange — the same program shape a
-            # (fuse x halo_depth)-deep chain round lowers to, so
-            # halo_depth=k at depth d is bitwise identical to
-            # halo_depth=1 at depth k*d. Geometry was validated at
-            # construction; nsteps still bounds the final round.
-            fuse = min(fuse * self.halo_depth, nsteps,
-                       min(self.domain.local_shape))
-
-        def chain(fields_c, step, depth):
-            """``depth`` steps from one ``depth``-wide exchange."""
-            if overlap_xla:
-                # Split-phase round (docs/OVERLAP.md): issue the same
-                # corner-propagated exchange with no consumer on the
-                # interior chain's dataflow path, run the chain on a
-                # frozen-constant frame, then stitch the k-thick
-                # sharded-face bands recomputed from the arrived frame
-                # — bitwise the same values.
-                self.overlap_applied = True
-                pending = halo.start_exchange(
-                    fields_c, boundaries, AXIS_NAMES, dims, depth
-                )
-                frozen = halo.frozen_frame(fields_c, boundaries, depth)
-                fields_i = temporal.window_chain(
-                    frozen, params, model, depth=depth, step=step,
-                    origin=offs - depth, row=L, use_noise=use_noise,
-                    unit_noise=unit_noise, boundaries=boundaries,
-                    final_pin=padded, compute_dtype=cdt,
-                )
-                fields_w = pending.finish()
-                return temporal.stitch_bands_from_frame(
-                    fields_i, fields_w, params, model, depth=depth,
-                    step=step, offs=offs, row=L, axis_sizes=dims,
-                    use_noise=use_noise, unit_noise=unit_noise,
-                    boundaries=boundaries, compute_dtype=cdt,
-                )
-            fields_w = halo.halo_pad_wide(
-                fields_c, boundaries, AXIS_NAMES, dims, depth
-            )
-            # Global-coordinate pinning per stage: ring cells outside
-            # the domain AND, for non-divisible L, pad cells inside the
-            # block — both must read back as the frozen ghost. The
-            # final stage (m_out == 0) has no ring, so divisible-L runs
-            # skip its provably-all-true mask (final_pin).
-            return temporal.window_chain(
-                fields_w, params, model, depth=depth, step=step,
-                origin=offs - depth, row=L, use_noise=use_noise,
-                unit_noise=unit_noise, boundaries=boundaries,
-                final_pin=padded, compute_dtype=cdt,
-            )
-
-        return run_chain_rounds(chain, fuse, fields)
+            # (global interpreter state), and under vmap interpret mode
+            # re-interprets per member: both take the kernel's XLA
+            # fallback, the same elementwise program, bitwise.
+            allow_interpret=not self.sharded and not self.is_ensemble,
+        )
+        fields, split = schedule.run_chain_rounds(
+            partial(round_fn, blk), depth, nsteps, step0, tuple(fields)
+        )
+        self.overlap_applied |= split
+        return fields
 
     def _make_step_fn(self, nsteps: int, mesh=None):
         """The un-jitted ``nsteps``-step advance — one construction
