@@ -9,7 +9,7 @@ carries — the number the HLO regression tests and the icimodel
 calibration loop reason about), the whole-field copy census (loop-carry
 copies an out-of-place kernel would force), the whole-field relayout
 census (pads, slices and concatenates of field size around a launch),
-compile wall time, and the
+the XLA step's slab count (``step_slabs``), compile wall time, and the
 persistent compilation cache outcome (hit/miss) per executable.
 Records land in three places at capture time: ``sim.executables``
 (merged into the RunStats ``executables`` section by the driver), one
@@ -229,14 +229,19 @@ def capture(compiled, *, name: str, compile_s: float,
             cache_dir: Optional[str] = None,
             cache_before: Optional[frozenset] = None,
             extra: Optional[dict] = None,
-            n_fields: Optional[int] = None) -> dict:
+            n_fields: Optional[int] = None,
+            step_slabs: Optional[int] = None) -> dict:
     """One executable's analytics record, from a ``jax`` AOT-compiled
     object. Every query is individually best-effort. ``n_fields``
     (the step runner's leading field arguments) arms the
-    ``field_copies`` and ``field_relayouts`` censuses."""
+    ``field_copies`` and ``field_relayouts`` censuses; ``step_slabs``
+    (the XLA step's x-slabs, ``Simulation.step_slabs``; None on a
+    Pallas runner) is recorded beside them."""
     rec = {"name": name, "compile_s": round(compile_s, 6)}
     if extra:
         rec.update(extra)
+    if step_slabs is not None:
+        rec["step_slabs"] = step_slabs
 
     try:
         cost = compiled.cost_analysis()
@@ -315,6 +320,9 @@ def publish(rec: dict, *, metrics=None, events=None) -> None:
     metrics.counter("compiles").inc()
     g = metrics.gauge("compile_s_last")
     g.set(rec.get("compile_s"))
+    if "step_slabs" in rec:
+        metrics.gauge("step_slabs", runner=rec["name"]).set(
+            rec["step_slabs"])
     cache = rec.get("cache")
     if cache == "hit":
         metrics.counter("compile_cache_hits").inc()
@@ -381,6 +389,7 @@ def instrument_compile(sim, fn, nsteps: int):
         extra={"nsteps": nsteps,
                "kernel": sim.kernel_language,
                "model": sim.model.name},
+        step_slabs=sim.step_slabs(nsteps),
     )
     sim.executables.append(rec)
     from .events import get_events

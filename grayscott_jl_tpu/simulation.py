@@ -1086,13 +1086,7 @@ class Simulation:
                 key, step_idx, offsets, shape, L, dtype
             )
 
-        round_fn, depth = schedule.select(
-            self.kernel_language, self.sharded, nsteps=nsteps,
-            fuse=self._fuse_base(), halo_depth=self.halo_depth,
-            local=self.domain.local_shape, dims=self.domain.dims,
-            dtype=self.dtype, n_fields=model.n_fields,
-            overlap=self.comm_overlap,
-        )
+        sched = self._schedule(nsteps)
         blk = schedule.Block(
             model=model, params=params, key=key, offs=offs, row=L,
             dims=self.domain.dims, use_noise=self.use_noise,
@@ -1106,10 +1100,37 @@ class Simulation:
             allow_interpret=not self.sharded and not self.is_ensemble,
         )
         fields, split = schedule.run_chain_rounds(
-            partial(round_fn, blk), depth, nsteps, step0, tuple(fields)
+            partial(sched.round, blk), sched.depth, nsteps, step0,
+            tuple(fields), slab=sched.slab,
         )
         self.overlap_applied |= split
         return fields
+
+    def _schedule(self, nsteps: int) -> schedule.Schedule:
+        """The ``nsteps`` runner's chain schedule
+        (``parallel/schedule.select``) on this block."""
+        return schedule.select(
+            self.kernel_language, self.sharded, nsteps=nsteps,
+            fuse=self._fuse_base(), halo_depth=self.halo_depth,
+            local=self.domain.local_shape, dims=self.domain.dims,
+            dtype=self.dtype, n_fields=self.model.n_fields,
+            overlap=self.comm_overlap,
+        )
+
+    def step_slabs(self, nsteps: int):
+        """Slabs per XLA step of the ``nsteps`` runner (1 where the
+        step is not blocked); None for a Pallas runner."""
+        return self._schedule(nsteps).step_slabs
+
+    def _compiler_options(self, nsteps: int):
+        """The ``nsteps`` runner's compiler options: those of the
+        blocked XLA step on TPU
+        (``schedule.SLAB_STEP_TPU_COMPILER_OPTIONS``), else none."""
+        device = (self.device if self.mesh is None
+                  else self.mesh.devices.flat[0])
+        if self._schedule(nsteps).slab is None or device.platform != "tpu":
+            return None
+        return dict(schedule.SLAB_STEP_TPU_COMPILER_OPTIONS)
 
     def _make_step_fn(self, nsteps: int, mesh=None):
         """The un-jitted ``nsteps``-step advance — one construction
@@ -1144,6 +1165,7 @@ class Simulation:
         fn = jax.jit(
             _gs_step(self._make_step_fn(nsteps)),
             donate_argnums=tuple(range(nf)),
+            compiler_options=self._compiler_options(nsteps),
         )
         return self._register_runner(nsteps, fn)
 
@@ -1269,6 +1291,7 @@ class Simulation:
                 fn = jax.jit(
                     _gs_step(self._make_step_fn(nsteps, mesh)),
                     donate_argnums=tuple(range(self.model.n_fields)),
+                    compiler_options=self._compiler_options(nsteps),
                 )
                 entry = self._replay_fns[key] = (fn, sharding, device)
             fn, sharding, device = entry

@@ -282,3 +282,74 @@ def test_chain_loop_updates_fields_in_place(topo, one_chip, mosaic, form):
     assert "output_to_operand_aliasing" in hlo
     assert xstats.field_copies(hlo, 2) == 0
     assert xstats.field_relayouts(hlo, 2) == 0
+
+
+@pytest.fixture
+def float64():
+    """64-bit mode on and the persistent cache off (as ``mosaic``)
+    for a Float64 XLA compile, restored after."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", True)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_x64", x64)
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("slab", [None, 8], ids=["whole", "slabs"])
+def test_blocked_xla_step_loop_holds_no_whole_field(one_chip, float64, slab):
+    """Three Float64 XLA steps of a 32x128x128 block in a
+    ``lax.fori_loop``, with the blocked step's compiler options: in
+    slabs, the loop carries only 8-plane slabs, so no op inside it has
+    a whole field (or a whole float32 half of one) as an operand; the
+    whole step's loop does. The split and the join run once, outside
+    the loop."""
+    import re
+    from functools import partial
+
+    from grayscott_jl_tpu.ops import noise
+    from grayscott_jl_tpu.parallel import schedule
+
+    shape, row = (32, 128, 128), 128
+    model = get_model("grayscott")
+    settings = Settings(L=row, noise=0.1, precision="Float64",
+                        backend="TPU", kernel_language="XLA")
+
+    def described(shp, dtype):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda p: described(p.shape, p.dtype),
+                          model.make_params(settings, jnp.float64))
+
+    def run(fields, key, params):
+        blk = schedule.Block(
+            model=model, params=params, key=key,
+            offs=jnp.zeros((3,), jnp.int32), row=row, dims=(1, 1, 1),
+            use_noise=True,
+            unit_noise=lambda step, offs, shp: noise.uniform_pm1_block(
+                key, step, offs, shp, row, jnp.float64),
+            pin=tuple, compute_dtype=jnp.float64, overlap=False,
+            padded=False, allow_interpret=False,
+        )
+        out, _ = schedule.run_chain_rounds(
+            partial(schedule.xla_step_round if slab is None
+                    else schedule.xla_slab_round, blk),
+            1, 3, jnp.int32(0),
+            fields, slab=slab,
+        )
+        return out
+
+    hlo = jax.jit(
+        run, donate_argnums=0,
+        compiler_options=schedule.SLAB_STEP_TPU_COMPILER_OPTIONS,
+    ).lower((described(shape, jnp.float64),) * 2,
+            described((2,), jnp.int32), params).compile().as_text()
+    (body,) = re.findall(r"body=%([\w.\-]+)", hlo)
+    loop = re.search(r"^%" + re.escape(body) + r" .*?^}", hlo,
+                     re.M | re.S).group(0)
+    assert ("[32,128,128]" in loop) == (slab is None)
